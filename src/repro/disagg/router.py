@@ -35,6 +35,8 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
 from repro.core.request import Request, RequestState
 from repro.core.scheduler import ChunkedPrefillScheduler, SchedulerConfig
 from repro.disagg.handoff import (
@@ -615,10 +617,13 @@ def build_disagg(
     host_max_bytes: Optional[int] = None,
     host_kv_dtype: str = "auto",
 ) -> DisaggregatedRouter:
-    """Construct a whole fleet: per-replica engines (sharing ONE set of
-    parameters — every replica must hold identical weights for a handoff to
-    be exact), pools, and schedulers.  With fairness configured, one shared
-    VirtualTokenCounter spans all schedulers (VTC anti-laundering).
+    """Construct a whole fleet: per-replica engines (ONE set of parameters —
+    every replica must hold identical weights for a handoff to be exact),
+    pools, and schedulers.  Replica ``i`` lives on ``jax.devices()[i % n]``
+    with its own copy of the weights there, so a four-chip host runs one
+    replica per chip (one device: every replica shares it).  With fairness
+    configured, one shared VirtualTokenCounter spans all schedulers (VTC
+    anti-laundering).
 
     ``host_max_bytes`` caps ONE host tier shared by every replica pool AND
     the handoff store — in-flight records charge the same budget staged
@@ -639,10 +644,12 @@ def build_disagg(
 
         tier = HostTier(host_max_bytes)
     params = None
+    devices = jax.devices()
     replicas: List[ReplicaServer] = []
     for i in range(cfg.n_prefill + cfg.n_decode):
         role = "prefill" if i < cfg.n_prefill else "decode"
-        engine = JAXEngine(model_cfg, engine_cfg, params=params)
+        engine = JAXEngine(model_cfg, engine_cfg, params=params,
+                           device=devices[i % len(devices)])
         params = engine.params             # replicas share one weight set
         pool = pool_for_model(
             model_cfg, n_blocks=n_blocks, block_size=block_size,

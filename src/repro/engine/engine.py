@@ -1,17 +1,18 @@
 """Real-execution chunked-prefill engine: the paper's serving loop running
-actual JAX forward passes (tiny models on CPU; the identical program compiles
-for TPU).
+actual JAX forward passes (tiny models on CPU in the tests; ``chip_smoke.py``
+runs it on a TPU at published widths).
 
 Continuous batching with PAGED KV storage (vLLM layout, the default):
   * ``n_slots`` fixed *batch rows*; a request binds a slot at its FIRST
     scheduled chunk (late binding — queued or admission-delayed backlog pins
     nothing) and keeps it until it finishes or is preempted.
   * K/V live in a physical page pool ``(layers, n_blocks + 1, block_size,
-    kv_heads, head_dim)`` whose page ids are exactly the ``KVBlockPool``'s
-    block ids, addressed through per-slot block tables.  Capacity scales with
-    resident tokens, not ``n_slots x max_context``; prefix-cache hits need no
-    payload copy (the matched blocks' pages are still resident); the last
-    page is a write sink for padding lanes.
+    kv_heads * head_dim)`` (page rows flat: the layout a TPU keeps row-major
+    and the paged kernels DMA as stored) whose page ids are exactly the
+    ``KVBlockPool``'s block ids, addressed through per-slot block tables.
+    Capacity scales with resident tokens, not ``n_slots x max_context``;
+    prefix-cache hits need no payload copy (the matched blocks' pages are
+    still resident); the last page is a write sink for padding lanes.
   * One jitted step per scheduling round executes the ENTIRE mixed batch —
     decode slots advance by 1 token, prefill slots by their scheduled chunk,
     idle slots by 0 — under static bucketed shapes.  The step FUSES the
@@ -29,6 +30,9 @@ Continuous batching with PAGED KV storage (vLLM layout, the default):
     (``pipelined=False``), which is kept for A/B.
   * The scheduler under test is the real ``repro.core`` code; latencies are
     wall-clock, so the LPRS predictor can be trained on real measurements.
+  * Each engine lives on one device (``device``, default the first): its
+    weights, page pool and per-round inputs are placed there, so a fleet can
+    put one replica on each chip of a host.
 """
 from __future__ import annotations
 
@@ -129,13 +133,17 @@ class JAXEngine:
     """Executes ScheduledBatches with real forward passes."""
 
     def __init__(self, model_cfg: ModelConfig, cfg: Optional[EngineConfig] = None,
-                 params=None, kv_pool: Optional[KVBlockPool] = None):
+                 params=None, kv_pool: Optional[KVBlockPool] = None,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg or EngineConfig()
         self.model_cfg = model_cfg
         self.model: Model = build_model(model_cfg)
+        self.device = device or jax.devices()[0]
         rng = jax.random.PRNGKey(self.cfg.seed)
-        self.params = params if params is not None else self.model.init(rng)
-        self._rng = jax.random.PRNGKey(self.cfg.seed + 1)
+        # a replica on another device gets its own copy of shared weights
+        self.params = self._put(
+            params if params is not None else self.model.init(rng))
+        self._rng = self._put(jax.random.PRNGKey(self.cfg.seed + 1))
 
         B = self.cfg.n_slots
         self.slot_of: Dict[int, int] = {}          # req_id -> slot
@@ -177,6 +185,11 @@ class JAXEngine:
             ))
             self._owns_pool = True
         self._build_state()
+
+    def _put(self, x):
+        """Place host data (or another device's arrays) on this engine's
+        device."""
+        return jax.device_put(x, self.device)
 
     # -- physical KV layout ----------------------------------------------------
     def _build_state(self) -> None:
@@ -220,14 +233,14 @@ class JAXEngine:
             self._sink = self.kv_pool.cfg.n_blocks
             self.max_pages = math.ceil(S / bs) + 1
             n_kv = model_cfg.n_kv_heads * (2 if self._fused else 1)
-            kv_shape = (model_cfg.n_layers, self._n_phys, bs, n_kv, hd)
+            kv_shape = (model_cfg.n_layers, self._n_phys, bs, n_kv * hd)
             # device-resident block tables, refreshed with DIRTY-SLOT
             # incremental updates; _bt_host mirrors exactly what the device
             # holds, _bt_len tracks per-slot entries already uploaded
             self._bt_host = np.full((B, self.max_pages), self._sink, np.int32)
             self._bt_len = np.zeros((B,), np.int32)
             self._bt_dirty: set = set()
-            self.block_tables = jnp.asarray(self._bt_host)
+            self.block_tables = self._put(self._bt_host)
 
             def step(params, tokens, cache, lens, chunk_lens, block_tables,
                      last_token, use_last, sample_mask, rng):
@@ -257,12 +270,11 @@ class JAXEngine:
 
             donate = (2, 3, 5)     # cache, lens, last_token
 
-        if self._fused:
-            self.cache = {"kv": jnp.zeros(kv_shape, dt)}
-        else:
-            self.cache = {"k": jnp.zeros(kv_shape, dt), "v": jnp.zeros(kv_shape, dt)}
-        self.lens = jnp.zeros((B,), jnp.int32)
-        self.last_token = jnp.zeros((B,), jnp.int32)   # device-resident
+        dev = self.device
+        self.cache = {nm: jnp.zeros(kv_shape, dt, device=dev)
+                      for nm in self._cache_names()}
+        self.lens = jnp.zeros((B,), jnp.int32, device=dev)
+        self.last_token = jnp.zeros((B,), jnp.int32, device=dev)
         self._step = jax.jit(step, donate_argnums=donate)
 
     def bind_kv_pool(self, kv_pool: Optional[KVBlockPool]) -> None:
@@ -316,28 +328,21 @@ class JAXEngine:
         which changes the cache shape and invalidates everything compiled
         here — bind first, then warm up."""
         B = self.cfg.n_slots
-        off = jnp.zeros((B,), jnp.bool_)
         for C in self.cfg.chunk_buckets:
-            tokens = jnp.ones((B, C), jnp.int32)
-            chunk_lens = jnp.zeros((B,), jnp.int32).at[0].set(1)
             self._rng, sub = jax.random.split(self._rng)
-            args = (self.params, tokens, self.cache, self.lens, chunk_lens)
-            if self.cfg.paged_kv:
-                args += (self.block_tables,)
-            args += (self.last_token, off, off)
-            out = self._step(*args, sub)
+            out = self._step(*self._dummy_round(C, sub))
             toks, self.cache, self.lens, self.last_token = out[:4]
             jax.block_until_ready(toks)
         # reset cache/lens state touched by the dummy rounds (paged writes all
         # land in the sink page, which is never read back)
-        self.lens = jnp.zeros((B,), jnp.int32)
+        self.lens = jnp.zeros((B,), jnp.int32, device=self.device)
         if self.cfg.paged_kv:
             # pre-compile every dirty-row scatter bucket the runtime can hit
             # (slot 0's current mirror row rewritten in place — a data no-op)
             for k in sorted({_pow2_bucket(n) for n in range(1, B + 1)}):
                 idx = np.zeros((k,), np.int32)
-                self.block_tables = self.block_tables.at[jnp.asarray(idx)].set(
-                    jnp.asarray(self._bt_host[idx])
+                self.block_tables = self.block_tables.at[self._put(idx)].set(
+                    self._put(self._bt_host[idx])
                 )
             jax.block_until_ready(self.block_tables)
         if include_swap is None:
@@ -345,6 +350,27 @@ class JAXEngine:
         if include_swap:
             self._prewarm_swap_shapes()
         self.warmed = True
+
+    def _dummy_round(self, C: int, rng):
+        """Step arguments of a throwaway round at chunk bucket ``C``: slot 0
+        takes one token (paged: into the sink page), every other slot idles
+        and no sampled token is kept."""
+        B = self.cfg.n_slots
+        off = self._put(np.zeros((B,), np.bool_))
+        chunk_lens = np.zeros((B,), np.int32)
+        chunk_lens[0] = 1
+        args = (self.params, self._put(np.ones((B, C), np.int32)), self.cache,
+                self.lens, self._put(chunk_lens))
+        if self.cfg.paged_kv:
+            args += (self.block_tables,)
+        return args + (self.last_token, off, off, rng)
+
+    def step_hlo(self, C: int) -> str:
+        """Compiled HLO text of the round step at chunk bucket ``C``.  On a
+        TPU, Mosaic-compiled Pallas kernels show up as ``tpu_custom_call``;
+        interpreted ones would not."""
+        args = self._dummy_round(C, self._rng)
+        return self._step.lower(*args).compile().as_text()
 
     def _prewarm_swap_shapes(self) -> None:
         """Compile the swap gather/scatter for every page-id bucket a swap
@@ -356,12 +382,13 @@ class JAXEngine:
             buckets = sorted({_pow2_bucket(n)
                               for n in range(1, self.max_pages + 1)})
             q8 = self._host_quantized()
+            hd = self.model_cfg.resolved_head_dim
             for k in buckets:
-                ids = jnp.full((k,), self._sink, jnp.int32)   # sink-only: no-op
+                ids = self._put(np.full((k,), self._sink, np.int32))  # no-op
                 for nm in names:
                     if q8:
                         q, scales = gather_swap_pages_q8(
-                            self.cache[nm], ids,
+                            self.cache[nm], ids, head_dim=hd,
                             use_pallas=self.cfg.use_pallas)
                         self.cache[nm] = scatter_swap_pages_q8(
                             self.cache[nm], ids, q, scales,
@@ -376,7 +403,7 @@ class JAXEngine:
             jax.block_until_ready(self.cache[names[0]])
         else:
             k_row = np.asarray(self.cache["k"][:, 0])
-            self.cache["k"] = self.cache["k"].at[:, 0].set(jnp.asarray(k_row))
+            self.cache["k"] = self.cache["k"].at[:, 0].set(self._put(k_row))
             jax.block_until_ready(self.cache["k"])
 
     # -- slot management -------------------------------------------------------
@@ -459,13 +486,15 @@ class JAXEngine:
         assert slot is not None, f"swap_out of unbound req {req.req_id}"
         if self.cfg.paged_kv:
             ids, _n = self._swap_page_ids(req.req_id)
-            jids = jnp.asarray(ids)
+            jids = self._put(ids)
             if self._host_quantized():
                 # fused gather+quantize: the host copy moves int8 pages plus
                 # small per-page-per-head scales — about half the bytes
                 arrays = tuple(
-                    gather_swap_pages_q8(self.cache[nm], jids,
-                                         use_pallas=self.cfg.use_pallas)
+                    gather_swap_pages_q8(
+                        self.cache[nm], jids,
+                        head_dim=self.model_cfg.resolved_head_dim,
+                        use_pallas=self.cfg.use_pallas)
                     for nm in self._cache_names()
                 )
             else:
@@ -529,7 +558,7 @@ class JAXEngine:
                 f"req {req.req_id}: restore bucket {ids.shape[0]} != staged "
                 f"{staged_pages}"
             )
-            jids = jnp.asarray(ids)
+            jids = self._put(ids)
             for nm, a in zip(names, payload):
                 self._scatter_staged(nm, jids, a)
             # table changed wholesale: force a full device row rewrite
@@ -538,7 +567,7 @@ class JAXEngine:
             self._bt_dirty.add(slot)
         else:
             for nm, a in zip(names, payload):
-                self.cache[nm] = self.cache[nm].at[:, slot].set(jnp.asarray(a))
+                self.cache[nm] = self.cache[nm].at[:, slot].set(self._put(a))
         self.lens = self.lens.at[slot].set(tokens)
 
     def _scatter_staged(self, nm: str, jids, staged) -> None:
@@ -547,11 +576,11 @@ class JAXEngine:
         if isinstance(staged, tuple):
             q, scales = staged
             self.cache[nm] = scatter_swap_pages_q8(
-                self.cache[nm], jids, jnp.asarray(q), jnp.asarray(scales),
+                self.cache[nm], jids, self._put(q), self._put(scales),
                 use_pallas=self.cfg.use_pallas)
         else:
             self.cache[nm] = scatter_swap_pages(
-                self.cache[nm], jids, jnp.asarray(staged),
+                self.cache[nm], jids, self._put(staged),
                 use_pallas=self.cfg.use_pallas)
 
     @staticmethod
@@ -605,7 +634,7 @@ class JAXEngine:
         assert kpad == staged_pages, (
             f"req {req.req_id}: tail bucket {kpad} != staged {staged_pages}"
         )
-        jids = jnp.asarray(ids)
+        jids = self._put(ids)
         for nm, a in zip(names, payload):
             self._scatter_staged(nm, jids, a)
         tokens = self.kv_pool.lens.get(req.req_id, 0)
@@ -754,8 +783,8 @@ class JAXEngine:
             # only ever compiles the shapes warmup pre-compiled
             k = _pow2_bucket(len(rows))
             rows = np.asarray(rows + [rows[0]] * (k - len(rows)), np.int32)
-            self.block_tables = self.block_tables.at[jnp.asarray(rows)].set(
-                jnp.asarray(self._bt_host[rows])
+            self.block_tables = self.block_tables.at[self._put(rows)].set(
+                self._put(self._bt_host[rows])
             )
             self._bt_dirty.clear()
 
@@ -809,12 +838,12 @@ class JAXEngine:
         caller goes back to scheduling.  The sampled-token readback starts as
         an async device->host copy; ``drain`` collects it one round later."""
         tokens, chunk_lens, use_last, sample_mask, sampled = self._stage(batch)
-        args = (self.params, jnp.asarray(tokens), self.cache, self.lens,
-                jnp.asarray(chunk_lens))
+        args = (self.params, self._put(tokens), self.cache, self.lens,
+                self._put(chunk_lens))
         if self.cfg.paged_kv:
             self._sync_block_tables(batch)
             args += (self.block_tables,)
-        args += (self.last_token, jnp.asarray(use_last), jnp.asarray(sample_mask))
+        args += (self.last_token, self._put(use_last), self._put(sample_mask))
         self._rng, sub = jax.random.split(self._rng)
         t_dispatch = time.perf_counter()
         if self._t_ready is not None:

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
@@ -115,7 +117,7 @@ def chunked_prefill_attention(
     *,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     B, Sq, Hq, hd = q.shape
     Skv, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -171,7 +173,7 @@ def chunked_prefill_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_offset.astype(jnp.int32), kv_lens.astype(jnp.int32), q_t, k_t, v_t)
 
     return out.transpose(0, 2, 1, 3)       # (B, Sq, Hq, hd)

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_K = 256
 
 NEG_INF = -1e30
@@ -92,7 +94,7 @@ def decode_attention(
     kv_lens,      # (B,) int32
     *,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     B, Hq, hd = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -138,7 +140,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kv_lens.astype(jnp.int32), q_g, k_t, v_t)
 
     return out.reshape(B, Hq, hd)

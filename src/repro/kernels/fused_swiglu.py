@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_F = 256
 
@@ -71,7 +73,7 @@ def fused_swiglu(
     *,
     block_m: int = DEFAULT_BLOCK_M,
     block_f: int = DEFAULT_BLOCK_F,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     M, D = x.shape
     F = w_gate.shape[1]
@@ -94,5 +96,5 @@ def fused_swiglu(
         out_specs=pl.BlockSpec((block_m, D), lambda mi, fi: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, D), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_gate, w_up, w_down)

@@ -2,11 +2,14 @@
 
 ``use_pallas`` selects kernel vs pure-jnp oracle; on CPU the kernels run in
 interpret mode (Python-executed kernel bodies — correctness, not speed); on
-TPU the same calls compile to Mosaic.  The engine flips this with one flag.
+TPU the same calls compile to Mosaic; any other platform is refused
+(``repro.kernels.interpret_mode``).  The engine flips this with one flag.
+
+Paged pools arrive as ``(n_pages, page_size, H, hd)`` or flattened
+``(n_pages, page_size, H*hd)`` (the engine's stored layout); the oracles see
+the former.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels import ref
 from repro.kernels.chunked_prefill_attention import chunked_prefill_attention
@@ -25,14 +28,10 @@ from repro.kernels.swap import (
     swap_scatter_pages_q8,
 )
 
-_ON_TPU = None
 
-
-def on_tpu() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.devices()[0].platform == "tpu"
-    return _ON_TPU
+def _heads(pages, head_dim):
+    """A paged pool as ``(n_pages, page_size, H, hd)`` for the oracles."""
+    return pages.reshape(pages.shape[:2] + (-1, head_dim))
 
 
 def prefill_chunk_attention(q, k_cache, v_cache, kv_lens, q_offset, *,
@@ -43,7 +42,7 @@ def prefill_chunk_attention(q, k_cache, v_cache, kv_lens, q_offset, *,
         return ref.chunked_prefill_attention_ref(q, k_cache, v_cache, kv_lens, q_offset)
     return chunked_prefill_attention(
         q, k_cache, v_cache, kv_lens, q_offset,
-        block_q=block_q, block_k=block_k, interpret=not on_tpu(),
+        block_q=block_q, block_k=block_k,
     )
 
 
@@ -53,7 +52,7 @@ def flash_decode_attention(q, k_cache, v_cache, kv_lens, *,
     if not use_pallas:
         return ref.decode_attention_ref(q, k_cache, v_cache, kv_lens)
     return decode_attention(
-        q, k_cache, v_cache, kv_lens, block_k=block_k, interpret=not on_tpu()
+        q, k_cache, v_cache, kv_lens, block_k=block_k
     )
 
 
@@ -67,12 +66,14 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables, kv_lens,
     step (the oracle is tile-size-agnostic: indirection is data movement);
     ``buffering_depth`` gathers run ahead of the dot (1 = synchronous)."""
     if not use_pallas:
+        hd = q.shape[-1]
         return ref.paged_prefill_attention_ref(
-            q, k_pages, v_pages, block_tables, kv_lens, q_offset)
+            q, _heads(k_pages, hd), _heads(v_pages, hd), block_tables,
+            kv_lens, q_offset)
     return paged_prefill_attention(
         q, k_pages, v_pages, block_tables, kv_lens, q_offset,
         block_q=block_q, pages_per_tile=pages_per_tile,
-        buffering_depth=buffering_depth, interpret=not on_tpu(),
+        buffering_depth=buffering_depth,
     )
 
 
@@ -85,11 +86,11 @@ def paged_prefill_chunk_attention_fused(q, kv_pages, block_tables, kv_lens,
     ``(n_pages, ps, 2*Hkv, hd)`` — one DMA per page feeds both K and V."""
     if not use_pallas:
         return ref.paged_prefill_attention_fused_ref(
-            q, kv_pages, block_tables, kv_lens, q_offset)
+            q, _heads(kv_pages, q.shape[-1]), block_tables, kv_lens, q_offset)
     return paged_prefill_attention_fused(
         q, kv_pages, block_tables, kv_lens, q_offset,
         block_q=block_q, pages_per_tile=pages_per_tile,
-        buffering_depth=buffering_depth, interpret=not on_tpu(),
+        buffering_depth=buffering_depth,
     )
 
 
@@ -99,12 +100,12 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables, kv_lens, *,
                                  buffering_depth: int = 1):
     """(B, Hq, hd) single-token decode vs a paged pool + block tables."""
     if not use_pallas:
+        hd = q.shape[-1]
         return ref.paged_decode_attention_ref(
-            q, k_pages, v_pages, block_tables, kv_lens)
+            q, _heads(k_pages, hd), _heads(v_pages, hd), block_tables, kv_lens)
     return paged_decode_attention(
         q, k_pages, v_pages, block_tables, kv_lens,
         pages_per_tile=pages_per_tile, buffering_depth=buffering_depth,
-        interpret=not on_tpu(),
     )
 
 
@@ -115,11 +116,10 @@ def paged_flash_decode_attention_fused(q, kv_pages, block_tables, kv_lens, *,
     """``paged_flash_decode_attention`` over a fused head-interleaved pool."""
     if not use_pallas:
         return ref.paged_decode_attention_fused_ref(
-            q, kv_pages, block_tables, kv_lens)
+            q, _heads(kv_pages, q.shape[-1]), block_tables, kv_lens)
     return paged_decode_attention_fused(
         q, kv_pages, block_tables, kv_lens,
         pages_per_tile=pages_per_tile, buffering_depth=buffering_depth,
-        interpret=not on_tpu(),
     )
 
 
@@ -130,7 +130,7 @@ def swiglu_ffn(x, w_gate, w_up, w_down, *, use_pallas: bool = True,
         return ref.fused_swiglu_ref(x, w_gate, w_up, w_down)
     return fused_swiglu(
         x, w_gate, w_up, w_down,
-        block_m=block_m, block_f=block_f, interpret=not on_tpu(),
+        block_m=block_m, block_f=block_f,
     )
 
 
@@ -139,7 +139,7 @@ def gather_swap_pages(pages, ids, *, use_pallas: bool = True):
     staging tensor (swap-out: the engine host-copies the result as a single
     dense DMA)."""
     return swap_gather_pages(
-        pages, ids, use_pallas=use_pallas, interpret=not on_tpu()
+        pages, ids, use_pallas=use_pallas
     )
 
 
@@ -147,15 +147,17 @@ def scatter_swap_pages(pages, ids, staged, *, use_pallas: bool = True):
     """Write a staging tensor back into freshly allocated physical pages
     (swap-in restore; ``pages`` is donated and updated in place)."""
     return swap_scatter_pages(
-        pages, ids, staged, use_pallas=use_pallas, interpret=not on_tpu()
+        pages, ids, staged, use_pallas=use_pallas
     )
 
 
-def gather_swap_pages_q8(pages, ids, *, use_pallas: bool = True):
+def gather_swap_pages_q8(pages, ids, *, head_dim: int,
+                         use_pallas: bool = True):
     """Gather + INT8-quantize staging pages in one fused pass (host tier
-    with ``host_kv_dtype="int8"``): returns ``(q, scales)``."""
+    with ``host_kv_dtype="int8"``): returns ``(q, scales)``, scaled per
+    ``head_dim``-lane head of the flat page row."""
     return swap_gather_pages_q8(
-        pages, ids, use_pallas=use_pallas, interpret=not on_tpu()
+        pages, ids, head_dim=head_dim, use_pallas=use_pallas
     )
 
 
@@ -164,6 +166,5 @@ def scatter_swap_pages_q8(pages, ids, q_staged, scales, *,
     """Dequantize + scatter INT8 staging pages back into physical pages
     (``pages`` donated and updated in place)."""
     return swap_scatter_pages_q8(
-        pages, ids, q_staged, scales, use_pallas=use_pallas,
-        interpret=not on_tpu()
+        pages, ids, q_staged, scales, use_pallas=use_pallas
     )

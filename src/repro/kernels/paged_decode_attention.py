@@ -1,22 +1,33 @@
 """Pallas TPU kernel: paged flash-decode attention (block-table K/V gather).
 
 The physical KV cache is a pool of fixed-size pages shared by all sequences
-(vLLM layout): ``k_pages/v_pages: (n_pages, page_size, Hkv, hd)``.  Each
-sequence owns a *block table* — the ordered list of physical page ids backing
-its logical token positions — so capacity scales with tokens actually
-resident, not ``n_slots x max_context``.
+(vLLM layout): ``k_pages/v_pages: (n_pages, page_size, Hkv, hd)``, or the
+same pool flattened row-major to ``(n_pages, page_size, Hkv*hd)`` — the
+layout the engine stores, because the kernels DMA pages from it as it
+stands (for a ``(.., Hkv, 64)`` array a TPU picks a layout with the page
+index minor, where no page is one contiguous block).
+Each sequence owns a *block table* — the ordered list of physical page ids
+backing its logical token positions — so capacity scales with tokens
+actually resident, not ``n_slots x max_context``.
 
 Indirection rides scalar prefetch: the block table and per-sequence kv
 lengths land in SMEM before the kernel body runs.  Each grid step covers one
 *tile* of ``pages_per_tile`` pages: the kernel issues one async copy per page
-(K and V live in compiler-placed memory, ``pltpu.ANY``), gathering the
+(K and V live in compiler-placed memory, ``pl.ANY``), gathering the
 scattered physical pages into a contiguous
-``(pages_per_tile * page_size, hd)`` VMEM tile, then runs one MXU dot over
+``(pages_per_tile * page_size, width)`` VMEM tile, then runs one MXU dot over
 the whole tile.  At small page sizes this is the difference between feeding
 the MXU 16-row slivers and feeding it full 128-row tiles — the per-tile
 online-softmax (m, l, acc) scratch carries across tiles exactly as the dense
 ``decode_attention`` kernel carries across KV blocks.  Tiles entirely past
 ``kv_len`` are skipped before any DMA is issued.
+
+Lane blocks: each copy moves ``width`` lanes of a page row — the narrowest
+run of whole heads that is a multiple of 128 lanes (``lane_block_heads``),
+since Mosaic refuses narrower lane slices.  At head_dim 64 a block holds two
+heads, so each query row is zero-padded into its own head's lanes
+(``place_heads``): the dot against the whole block scores only that head's
+keys, and the output keeps only its head's lanes (``take_heads``).
 
 Two orthogonal knobs hide the gather latency behind the MXU dot:
 
@@ -32,10 +43,11 @@ Two orthogonal knobs hide the gather latency behind the MXU dot:
   waited within the same inner tile loop — dead tiles still skip DMA
   entirely.
 * ``fused`` — the pool carries the head-interleaved layout
-  ``[K0,V0,K1,V1,...]`` (``kv_pages: (n_pages, page_size, 2*Hkv, hd)``,
-  viewed kernel-side as ``(n_pages, Hkv, 2, ps, hd)``), so ONE async copy
-  per page fetches both the K and V rows: half the page-table reads and
-  half the DMA issue count of the split layout.
+  ``[K0,V0,K1,V1,...]`` (``kv_pages: (n_pages, page_size, 2*Hkv, hd)``), so
+  a lane block holds each of its heads' K *and* V and ONE async copy per
+  page feeds both operands: half the page-table reads and half the DMA
+  issue count of the split layout.  The query sits in the K lanes and the
+  output is read from the V lanes of the same tile.
 """
 from __future__ import annotations
 
@@ -47,32 +59,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
-def _tile_copies(block_tables_ref, kv_h, t, slot, rest, *,
-                 page_size, pages_per_tile, fused, b):
-    """Async-copy descriptors gathering tile ``t`` into buffer ``slot``.
+def lane_block_heads(n_heads, head_dim, fused):
+    """KV heads per DMA'd lane block of a flattened page row.
+
+    A page row is ``Hkv*hd`` lanes (split) or ``2*Hkv*hd`` (fused: each
+    head's ``[K|V]`` is ``2*hd`` lanes).  Mosaic only DMAs lane slices that
+    are multiples of 128, so each kernel program fetches the narrowest run
+    of whole heads that is: one head at hd 128, two at hd 64 (one K+V pair
+    when fused).  A row that is no multiple of 128 lanes (the CPU tests'
+    tiny configs) is taken whole, which only interpret mode runs."""
+    unit = head_dim * (2 if fused else 1)
+    for r in range(1, n_heads + 1):
+        if n_heads % r == 0 and (r * unit) % 128 == 0:
+            return r
+    return n_heads
+
+
+def place_heads(x, slot, n_slots):
+    """Widen ``(..., hd)`` to ``(..., n_slots*hd)`` lanes with ``x`` in lane
+    slot ``slot`` (broadcast over the leading dims) and zeros elsewhere: a
+    zero-padded query dotted with a whole lane block scores only the keys
+    in its own head's lanes."""
+    hit = jnp.arange(n_slots) == slot[..., None]
+    out = jnp.where(hit[..., None], x[..., None, :], 0)
+    return out.reshape(*out.shape[:-2], n_slots * x.shape[-1])
+
+
+def take_heads(o, slot, n_slots):
+    """Inverse of ``place_heads``: keep lane slot ``slot`` of each row of a
+    lane-block output (the other slots hold other heads' values)."""
+    o = o.reshape(*o.shape[:-1], n_slots, o.shape[-1] // n_slots)
+    hit = jnp.arange(n_slots) == slot[..., None]
+    return jnp.where(hit[..., None], o, 0).sum(axis=-2).astype(o.dtype)
+
+
+def _tile_copies(block_tables_ref, blk, t, slot, pools, tiles, sem, *,
+                 page_size, pages_per_tile, width, b):
+    """Async-copy descriptors gathering tile ``t``'s lane block ``blk`` into
+    buffer ``slot``: one ``(page_size, width)`` copy per page and pool.
 
     The same descriptors are built twice — once to ``start()`` the DMAs,
     once to ``wait()`` them (a descriptor is just (src, dst, sem))."""
+    lanes = pl.ds(pl.multiple_of(blk * width, width), width)
     out = []
     for j in range(pages_per_tile):
         pid = block_tables_ref[b, t * pages_per_tile + j]
-        if fused:
-            kv_hbm, kv_tile, sem = rest
-            # one copy moves the page's full (2, ps, hd) K+V block
+        rows = pl.ds(j * page_size, page_size)
+        for i, (hbm, tile) in enumerate(zip(pools, tiles)):
             out.append(pltpu.make_async_copy(
-                kv_hbm.at[pid, kv_h], kv_tile.at[slot, j], sem.at[slot, 0, j]
-            ))
-        else:
-            k_hbm, v_hbm, k_tile, v_tile, sem = rest
-            dst = pl.ds(j * page_size, page_size)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[pid, kv_h], k_tile.at[slot, dst, :], sem.at[slot, 0, j]
-            ))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[pid, kv_h], v_tile.at[slot, dst, :], sem.at[slot, 1, j]
+                hbm.at[pid, :, lanes], tile.at[slot, rows, :], sem.at[slot, i, j]
             ))
     return out
 
@@ -80,24 +120,22 @@ def _tile_copies(block_tables_ref, kv_h, t, slot, rest, *,
 def _paged_decode_kernel(
     block_tables_ref,   # (B, n_tiles * pages_per_tile) scalar prefetch
     kv_len_ref,         # (B,) scalar prefetch
-    q_ref,              # (group, hd)
-    *refs,              # split: k_hbm, v_hbm | fused: kv_hbm; then o_ref + scratch
+    q_ref,              # (rows, width) lane-placed queries of one lane block
+    *refs,              # pools (1 fused | 2 split), o_ref, m, l, acc, tiles, sem
     page_size: int,
     pages_per_tile: int,
     sm_scale: float,
     depth: int,
     n_tiles: int,
-    fused: bool,
+    n_pools: int,
+    width: int,
 ):
-    if fused:
-        kv_hbm, o_ref, m_ref, l_ref, acc_ref, kv_tile, sem = refs
-        dma_refs = (kv_hbm, kv_tile, sem)
-    else:
-        k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, k_tile, v_tile, sem = refs
-        dma_refs = (k_hbm, v_hbm, k_tile, v_tile, sem)
+    pools = refs[:n_pools]
+    o_ref, m_ref, l_ref, acc_ref = refs[n_pools:n_pools + 4]
+    tiles, sem = refs[n_pools + 4:-1], refs[-1]
 
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    blk = pl.program_id(1)
     tile_i = pl.program_id(2)
     tile = page_size * pages_per_tile
 
@@ -109,8 +147,9 @@ def _paged_decode_kernel(
 
     def copies(t, slot):
         return _tile_copies(
-            block_tables_ref, h, t, slot, dma_refs, page_size=page_size,
-            pages_per_tile=pages_per_tile, fused=fused, b=b,
+            block_tables_ref, blk, t, slot, pools, tiles, sem,
+            page_size=page_size, pages_per_tile=pages_per_tile, width=width,
+            b=b,
         )
 
     @pl.when(tile_i == 0)
@@ -139,22 +178,16 @@ def _paged_decode_kernel(
     def _compute():
         for c in copies(tile_i, slot):
             c.wait()
-        if fused:
-            kv = kv_tile[slot]                                # (ppt, 2, ps, hd)
-            hd = kv.shape[-1]
-            k = kv[:, 0].reshape(tile, hd)
-            v = kv[:, 1].reshape(tile, hd)
-        else:
-            k = k_tile[slot]                                  # (tile, hd)
-            v = v_tile[slot]
+        k = tiles[0][slot]                                # (tile, width)
+        v = tiles[-1][slot]                               # fused: same tile
 
         tile_start = tile_i * tile
         k_pos = tile_start + jax.lax.iota(jnp.int32, tile)
-        q = q_ref[...].astype(jnp.float32) * sm_scale         # (g, hd)
+        q = q_ref[...].astype(jnp.float32) * sm_scale     # (rows, width)
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                     # (g, tile)
+        )                                                 # (rows, tile)
         mask = k_pos[None, :] < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
@@ -192,58 +225,59 @@ def _pad_tables(block_tables, pages_per_tile):
     return block_tables, n_tiles
 
 
-def _fused_kernel_view(kv_pages):
-    """(n_pages, ps, 2*Hkv, hd) head-interleaved pool -> the kernel-side
-    (n_pages, Hkv, 2, ps, hd) view: ``.at[pid, kv_h]`` is one page's K+V."""
-    n_pages, ps, H2, hd = kv_pages.shape
-    return kv_pages.reshape(n_pages, ps, H2 // 2, 2, hd).transpose(0, 2, 3, 1, 4)
+def _kernel_scratch(depth, tile, width, rows, dtype, n_pools,
+                    pages_per_tile):
+    """Online-softmax (m, l, acc) carries plus ``n_pools`` multi-buffered
+    K/V tiles and their DMA semaphores."""
+    return [
+        pltpu.VMEM((rows,), jnp.float32),
+        pltpu.VMEM((rows,), jnp.float32),
+        pltpu.VMEM((rows, width), jnp.float32),
+        *[pltpu.VMEM((depth, tile, width), dtype)] * n_pools,
+        pltpu.SemaphoreType.DMA((depth, n_pools, pages_per_tile)),
+    ]
 
 
-def _decode_scratch(depth, tile, pages_per_tile, page_size, hd, group,
-                    dtype, fused):
-    base = [
-        pltpu.VMEM((group,), jnp.float32),
-        pltpu.VMEM((group,), jnp.float32),
-        pltpu.VMEM((group, hd), jnp.float32),
-    ]
-    if fused:
-        return base + [
-            pltpu.VMEM((depth, pages_per_tile, 2, page_size, hd), dtype),
-            pltpu.SemaphoreType.DMA((depth, 1, pages_per_tile)),
-        ]
-    return base + [
-        pltpu.VMEM((depth, tile, hd), dtype),
-        pltpu.VMEM((depth, tile, hd), dtype),
-        pltpu.SemaphoreType.DMA((depth, 2, pages_per_tile)),
-    ]
+def flat_pools(pools, head_dim, fused):
+    """Row-major ``(n_pages, ps, lanes)`` views of the page pools plus the
+    kv head count and lane-block geometry ``(heads per block, width)``."""
+    n_pages, page_size = pools[0].shape[:2]
+    flat = tuple(p.reshape(n_pages, page_size, -1) for p in pools)
+    unit = head_dim * (2 if fused else 1)
+    n_kv = flat[0].shape[2] // unit
+    r = lane_block_heads(n_kv, head_dim, fused)
+    return flat, n_kv, r, r * unit
 
 
 def _paged_decode_call(q, pools, block_tables, kv_lens, *, pages_per_tile,
                        buffering_depth, interpret, fused):
     B, Hq, hd = q.shape
     page_size = pools[0].shape[1]
-    Hkv = pools[0].shape[2] // (2 if fused else 1)
+    pools, Hkv, r, width = flat_pools(pools, hd, fused)
     assert Hq % Hkv == 0, (Hq, Hkv)
     assert buffering_depth >= 1, buffering_depth
     group = Hq // Hkv
+    n_blk, rows = Hkv // r, r * group
+    n_slots = width // hd
 
     block_tables, n_tiles = _pad_tables(
         block_tables.astype(jnp.int32), pages_per_tile
     )
 
-    grid = (B, Hkv, n_tiles)
+    grid = (B, n_blk, n_tiles)
     kernel = functools.partial(
         _paged_decode_kernel, page_size=page_size,
         pages_per_tile=pages_per_tile, sm_scale=1.0 / math.sqrt(hd),
-        depth=buffering_depth, n_tiles=n_tiles, fused=fused,
+        depth=buffering_depth, n_tiles=n_tiles, n_pools=len(pools),
+        width=width,
     )
 
-    q_g = q.reshape(B, Hkv, group, hd)
-    if fused:
-        pool_ops = (_fused_kernel_view(pools[0]),)
-    else:
-        # pages laid out (n_pages, Hkv, page_size, hd): contiguous (ps, hd) tiles
-        pool_ops = (pools[0].transpose(0, 2, 1, 3), pools[1].transpose(0, 2, 1, 3))
+    # query row (j, g) of a block is q head (blk*r + j)*group + g: its K
+    # lanes are slot j (split) or 2j (fused), its output the V slot after
+    j_of_row = jnp.arange(rows) // group
+    k_slot = j_of_row * (2 if fused else 1)
+    v_slot = k_slot + (1 if fused else 0)
+    q_b = place_heads(q.reshape(B, n_blk, rows, hd), k_slot, n_slots)
 
     tile = page_size * pages_per_tile
     out = pl.pallas_call(
@@ -253,27 +287,27 @@ def _paged_decode_call(q, pools, block_tables, kv_lens, *, pages_per_tile,
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (None, None, group, hd),
+                    (None, None, rows, width),
                     lambda b, h, ti, *_: (b, h, 0, 0),
                 ),
                 # K/V stay unblocked: the kernel gathers pages itself via
                 # per-page async copies steered by the prefetched table
-                *([pl.BlockSpec(memory_space=pltpu.ANY)] * len(pool_ops)),
+                *([pl.BlockSpec(memory_space=pl.ANY)] * len(pools)),
             ],
             out_specs=pl.BlockSpec(
-                (None, None, group, hd),
+                (None, None, rows, width),
                 lambda b, h, ti, *_: (b, h, 0, 0),
             ),
-            scratch_shapes=_decode_scratch(
-                buffering_depth, tile, pages_per_tile, page_size, hd, group,
-                pools[0].dtype, fused,
+            scratch_shapes=_kernel_scratch(
+                buffering_depth, tile, width, rows, pools[0].dtype,
+                len(pools), pages_per_tile,
             ),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd), q.dtype),
-        interpret=interpret,
-    )(block_tables, kv_lens.astype(jnp.int32), q_g, *pool_ops)
+        out_shape=jax.ShapeDtypeStruct((B, n_blk, rows, width), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(block_tables, kv_lens.astype(jnp.int32), q_b, *pools)
 
-    return out.reshape(B, Hq, hd)
+    return take_heads(out, v_slot, n_slots).reshape(B, Hq, hd)
 
 
 @functools.partial(
@@ -281,14 +315,14 @@ def _paged_decode_call(q, pools, block_tables, kv_lens, *, pages_per_tile,
 )
 def paged_decode_attention(
     q,              # (B, Hq, hd) one token per sequence
-    k_pages,        # (n_pages, page_size, Hkv, hd) physical page pool
-    v_pages,        # (n_pages, page_size, Hkv, hd)
+    k_pages,        # (n_pages, page_size, Hkv, hd) or (.., Hkv*hd) pool
+    v_pages,        # same shape as k_pages
     block_tables,   # (B, max_pages) int32 physical page ids (pad: any valid id)
     kv_lens,        # (B,) int32 valid token counts
     *,
     pages_per_tile: int = 1,
     buffering_depth: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     return _paged_decode_call(
         q, (k_pages, v_pages), block_tables, kv_lens,
@@ -302,13 +336,13 @@ def paged_decode_attention(
 )
 def paged_decode_attention_fused(
     q,              # (B, Hq, hd)
-    kv_pages,       # (n_pages, page_size, 2*Hkv, hd) head-interleaved pool
+    kv_pages,       # (n_pages, page_size, 2*Hkv, hd) or (.., 2*Hkv*hd)
     block_tables,   # (B, max_pages) int32
     kv_lens,        # (B,) int32
     *,
     pages_per_tile: int = 1,
     buffering_depth: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     return _paged_decode_call(
         q, (kv_pages,), block_tables, kv_lens,

@@ -3,28 +3,32 @@
 The chunked-prefill engine's hot op against a *paged* KV cache: a chunk of Q
 tokens (one scheduling round) attends to its sequence's prefix KV plus its
 own keys with a causal offset, where K/V live in a shared physical page pool
-``(n_pages, page_size, Hkv, hd)`` addressed through a per-sequence block
-table (same layout as ``paged_decode_attention``).
+``(n_pages, page_size, Hkv, hd)`` — or its row-major flattening
+``(n_pages, page_size, Hkv*hd)``, which the engine stores — addressed
+through a per-sequence block table (same layout as
+``paged_decode_attention``).
 
 Grid: ``(B, Hq, Sq // block_q, n_tiles)`` — the innermost dimension walks the
 sequence's block table one *tile* of ``pages_per_tile`` pages at a time.  The
 prefetched table steers per-page async copies (K/V live in compiler-placed
-memory, ``pltpu.ANY``) that gather the scattered physical pages into one
-contiguous ``(pages_per_tile * page_size, hd)`` VMEM tile, so the MXU sees
+memory, ``pl.ANY``) that gather the scattered physical pages into one
+contiguous ``(pages_per_tile * page_size, width)`` VMEM tile, so the MXU sees
 wide K/V operands even at small page sizes; the online-softmax (m, l, acc)
 scratch carries across tiles exactly as the dense kernel carries across KV
 blocks.  Tiles entirely above the causal diagonal or past ``kv_len`` are
 skipped before any DMA is issued, so work stays ~O(prefix + chunk^2/2) per
 sequence regardless of pool size.
 
-``buffering_depth`` and the fused head-interleaved layout work exactly as in
-``paged_decode_attention`` (see its module docstring): tile ``t`` computes
-out of buffer slot ``t % depth`` while tile ``t+depth-1``'s gather is
-already in flight, and the fused pool needs only ONE async copy per page to
-feed both K and V.  Live tiles form a contiguous prefix (the causal bound
-``tile_start <= q_pos[-1]`` and the length bound ``tile_start < kv_len`` are
-both monotone in the tile index), so every issued copy is waited within the
-same inner tile loop.
+Each copy moves the 128-lane-aligned block of whole heads that holds the
+program's kv head, and the query head is zero-padded into its own lanes of
+that block — see ``paged_decode_attention``'s module docstring.
+``buffering_depth`` and the fused head-interleaved layout work exactly as
+there: tile ``t`` computes out of buffer slot ``t % depth`` while tile
+``t+depth-1``'s gather is already in flight, and the fused pool needs only
+ONE async copy per page to feed both K and V.  Live tiles form a contiguous
+prefix (the causal bound ``tile_start <= last query position`` and the
+length bound ``tile_start < kv_len`` are both monotone in the tile index),
+so every issued copy is waited within the same inner tile loop.
 """
 from __future__ import annotations
 
@@ -36,10 +40,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.paged_decode_attention import (
-    _fused_kernel_view,
+    _kernel_scratch,
     _pad_tables,
     _tile_copies,
+    flat_pools,
+    place_heads,
+    take_heads,
 )
 
 DEFAULT_BLOCK_Q = 128
@@ -53,23 +61,21 @@ def _paged_prefill_kernel(
     kv_len_ref,         # (B,) valid kv length (prefix + chunk)
     q_offset_ref,       # (B,) absolute position of q[:, 0]
     # blocked operands
-    q_ref,              # (blk_q, hd)
-    *refs,              # split: k_hbm, v_hbm | fused: kv_hbm; then o_ref + scratch
+    q_ref,              # (blk_q, width) lane-placed queries of one head
+    *refs,              # pools (1 fused | 2 split), o_ref, m, l, acc, tiles, sem
     block_q: int,
     page_size: int,
     pages_per_tile: int,
-    group: int,
+    heads_per_block: int,
     sm_scale: float,
     depth: int,
     n_tiles: int,
-    fused: bool,
+    n_pools: int,
+    width: int,
 ):
-    if fused:
-        kv_hbm, o_ref, m_ref, l_ref, acc_ref, kv_tile, sem = refs
-        dma_refs = (kv_hbm, kv_tile, sem)
-    else:
-        k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, k_tile, v_tile, sem = refs
-        dma_refs = (k_hbm, v_hbm, k_tile, v_tile, sem)
+    pools = refs[:n_pools]
+    o_ref, m_ref, l_ref, acc_ref = refs[n_pools:n_pools + 4]
+    tiles, sem = refs[n_pools + 4:-1], refs[-1]
 
     b = pl.program_id(0)
     h = pl.program_id(1)
@@ -80,19 +86,22 @@ def _paged_prefill_kernel(
     q_off = q_offset_ref[b]
 
     q_i = pl.program_id(2)
-    q_pos = q_off + q_i * block_q + jax.lax.iota(jnp.int32, block_q)
+    q_start = q_off + q_i * block_q
+    q_pos = q_start + jax.lax.iota(jnp.int32, block_q)
 
     def live(t):
         # whole-tile skip: above the causal diagonal or past the valid
-        # length — dead tiles issue no DMA
-        return (t * tile <= q_pos[-1]) & (t * tile < kv_len)
+        # length — dead tiles issue no DMA.  The last query position is a
+        # scalar: Mosaic cannot lower an element read of a vector.
+        return (t * tile <= q_start + block_q - 1) & (t * tile < kv_len)
 
-    kv_h = h // group
+    blk = h // heads_per_block
 
     def copies(t, slot):
         return _tile_copies(
-            block_tables_ref, kv_h, t, slot, dma_refs, page_size=page_size,
-            pages_per_tile=pages_per_tile, fused=fused, b=b,
+            block_tables_ref, blk, t, slot, pools, tiles, sem,
+            page_size=page_size, pages_per_tile=pages_per_tile, width=width,
+            b=b,
         )
 
     @pl.when(tile_i == 0)
@@ -121,14 +130,8 @@ def _paged_prefill_kernel(
     def _compute():
         for c in copies(tile_i, slot):
             c.wait()
-        if fused:
-            kv = kv_tile[slot]                                # (ppt, 2, ps, hd)
-            hd = kv.shape[-1]
-            k = kv[:, 0].reshape(tile, hd)
-            v = kv[:, 1].reshape(tile, hd)
-        else:
-            k = k_tile[slot]                                  # (tile, hd)
-            v = v_tile[slot]
+        k = tiles[0][slot]                                # (tile, width)
+        v = tiles[-1][slot]                               # fused: same tile
 
         tile_start = tile_i * tile
         k_pos = tile_start + jax.lax.iota(jnp.int32, tile)
@@ -159,34 +162,16 @@ def _paged_prefill_kernel(
         o_ref[...] = (acc_ref[...] / safe_l[:, None]).astype(o_ref.dtype)
 
 
-def _prefill_scratch(depth, tile, pages_per_tile, page_size, hd, block_q,
-                     dtype, fused):
-    base = [
-        pltpu.VMEM((block_q,), jnp.float32),
-        pltpu.VMEM((block_q,), jnp.float32),
-        pltpu.VMEM((block_q, hd), jnp.float32),
-    ]
-    if fused:
-        return base + [
-            pltpu.VMEM((depth, pages_per_tile, 2, page_size, hd), dtype),
-            pltpu.SemaphoreType.DMA((depth, 1, pages_per_tile)),
-        ]
-    return base + [
-        pltpu.VMEM((depth, tile, hd), dtype),
-        pltpu.VMEM((depth, tile, hd), dtype),
-        pltpu.SemaphoreType.DMA((depth, 2, pages_per_tile)),
-    ]
-
-
 def _paged_prefill_call(q, pools, block_tables, kv_lens, q_offset, *,
                         block_q, pages_per_tile, buffering_depth, interpret,
                         fused):
     B, Sq, Hq, hd = q.shape
     page_size = pools[0].shape[1]
-    Hkv = pools[0].shape[2] // (2 if fused else 1)
+    pools, Hkv, r, width = flat_pools(pools, hd, fused)
     assert Hq % Hkv == 0, (Hq, Hkv)
     assert buffering_depth >= 1, buffering_depth
     group = Hq // Hkv
+    n_slots = width // hd
 
     block_q = min(block_q, Sq)
     assert Sq % block_q == 0, (Sq, block_q)
@@ -198,16 +183,17 @@ def _paged_prefill_call(q, pools, block_tables, kv_lens, q_offset, *,
     grid = (B, Hq, Sq // block_q, n_tiles)
     kernel = functools.partial(
         _paged_prefill_kernel, block_q=block_q, page_size=page_size,
-        pages_per_tile=pages_per_tile, group=group,
+        pages_per_tile=pages_per_tile, heads_per_block=r * group,
         sm_scale=1.0 / math.sqrt(hd), depth=buffering_depth, n_tiles=n_tiles,
-        fused=fused,
+        n_pools=len(pools), width=width,
     )
 
-    q_t = q.transpose(0, 2, 1, 3)          # (B, Hq, Sq, hd)
-    if fused:
-        pool_ops = (_fused_kernel_view(pools[0]),)
-    else:
-        pool_ops = (pools[0].transpose(0, 2, 1, 3), pools[1].transpose(0, 2, 1, 3))
+    # q head h reads kv head h // group, slot j = (h // group) % r of its
+    # lane block: K lanes in slot j (split) or 2j (fused), output the V slot
+    j_of_head = (jnp.arange(Hq) // group) % r
+    k_slot = (j_of_head * (2 if fused else 1))[:, None]
+    v_slot = k_slot + (1 if fused else 0)
+    q_t = place_heads(q.transpose(0, 2, 1, 3), k_slot, n_slots)  # (B,Hq,Sq,W)
 
     tile = page_size * pages_per_tile
     out = pl.pallas_call(
@@ -217,30 +203,30 @@ def _paged_prefill_call(q, pools, block_tables, kv_lens, q_offset, *,
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
-                    (None, None, block_q, hd),
+                    (None, None, block_q, width),
                     lambda b, h, qi, ti, *_: (b, h, qi, 0),
                 ),
                 # K/V stay unblocked: the kernel gathers pages itself via
                 # per-page async copies steered by the prefetched table
-                *([pl.BlockSpec(memory_space=pltpu.ANY)] * len(pool_ops)),
+                *([pl.BlockSpec(memory_space=pl.ANY)] * len(pools)),
             ],
             out_specs=pl.BlockSpec(
-                (None, None, block_q, hd),
+                (None, None, block_q, width),
                 lambda b, h, qi, ti, *_: (b, h, qi, 0),
             ),
-            scratch_shapes=_prefill_scratch(
-                buffering_depth, tile, pages_per_tile, page_size, hd, block_q,
-                pools[0].dtype, fused,
+            scratch_shapes=_kernel_scratch(
+                buffering_depth, tile, width, block_q, pools[0].dtype,
+                len(pools), pages_per_tile,
             ),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, width), q.dtype),
+        interpret=resolve_interpret(interpret),
     )(
         block_tables, kv_lens.astype(jnp.int32), q_offset.astype(jnp.int32),
-        q_t, *pool_ops,
+        q_t, *pools,
     )
 
-    return out.transpose(0, 2, 1, 3)       # (B, Sq, Hq, hd)
+    return take_heads(out, v_slot, n_slots).transpose(0, 2, 1, 3)
 
 
 @functools.partial(
@@ -249,8 +235,8 @@ def _paged_prefill_call(q, pools, block_tables, kv_lens, q_offset, *,
 )
 def paged_prefill_attention(
     q,              # (B, Sq, Hq, hd) the prefill chunk's queries
-    k_pages,        # (n_pages, page_size, Hkv, hd) physical page pool
-    v_pages,        # (n_pages, page_size, Hkv, hd)
+    k_pages,        # (n_pages, page_size, Hkv, hd) or (.., Hkv*hd) pool
+    v_pages,        # same shape as k_pages
     block_tables,   # (B, max_pages) int32 physical page ids
     kv_lens,        # (B,) int32 valid KV length (prefix + chunk)
     q_offset,       # (B,) int32 absolute position of q[:, 0]
@@ -258,7 +244,7 @@ def paged_prefill_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     pages_per_tile: int = 1,
     buffering_depth: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     return _paged_prefill_call(
         q, (k_pages, v_pages), block_tables, kv_lens, q_offset,
@@ -273,7 +259,7 @@ def paged_prefill_attention(
 )
 def paged_prefill_attention_fused(
     q,              # (B, Sq, Hq, hd)
-    kv_pages,       # (n_pages, page_size, 2*Hkv, hd) head-interleaved pool
+    kv_pages,       # (n_pages, page_size, 2*Hkv, hd) or (.., 2*Hkv*hd)
     block_tables,   # (B, max_pages) int32
     kv_lens,        # (B,) int32
     q_offset,       # (B,) int32
@@ -281,7 +267,7 @@ def paged_prefill_attention_fused(
     block_q: int = DEFAULT_BLOCK_Q,
     pages_per_tile: int = 1,
     buffering_depth: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     return _paged_prefill_call(
         q, (kv_pages,), block_tables, kv_lens, q_offset,

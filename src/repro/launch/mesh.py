@@ -19,25 +19,32 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding rules here annotate
+    with ``with_sharding_constraint`` and leave propagation to the compiler,
+    which Explicit axes (the default since JAX 0.7) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Arbitrary mesh (hillclimb sweeps over layouts)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(n_model: Optional[int] = None) -> Mesh:
     """Tiny mesh over whatever devices exist (CPU tests: 1 device)."""
     n = len(jax.devices())
     nm = n_model or 1
-    return jax.make_mesh((n // nm, nm), ("data", "model"))
+    return _auto_mesh((n // nm, nm), ("data", "model"))
 
 
 def mesh_chips(mesh: Mesh) -> int:

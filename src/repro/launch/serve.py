@@ -25,6 +25,7 @@ from repro.engine.workload import (
     attach_prompt_tokens,
     sharegpt_like,
 )
+from repro.launch.compile_cache import place_compile_cache
 
 
 def profile_and_train_predictor(
@@ -165,7 +166,9 @@ def main(argv=None):
                     help="LPRS target latency (0 = auto from profiling median)")
     ap.add_argument("--apc", action="store_true")
     ap.add_argument("--pallas", action="store_true",
-                    help="run the Pallas kernels (interpret mode on CPU)")
+                    help="run the Pallas kernels instead of the jnp gather "
+                         "oracles: compiled by Mosaic on a TPU, interpreted "
+                         "(slowly) on the CPU; other platforms are refused")
     ap.add_argument("--dense-kv", action="store_true",
                     help="dense slot-indexed KV cache instead of the paged "
                          "block-table layout (A/B baseline; outputs are "
@@ -245,6 +248,7 @@ def main(argv=None):
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     if args.disagg:
         return run_disagg(args)

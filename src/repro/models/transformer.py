@@ -298,15 +298,17 @@ class TransformerLM:
 
         Same Sarathi round semantics and bit-level math as the dense path, but
         K/V live in a shared physical page pool ``(L, n_pages, page_size,
-        Hkv, hd)`` addressed through per-slot block tables ``(B, max_pages)``
-        instead of a ``(L, B, S+1, ...)`` slot-dense tensor.  New K/V for
+        Hkv*hd)`` (the engine's layout: each page row flat, so the kernels
+        DMA it as stored; ``(.., Hkv, hd)`` works too) addressed through
+        per-slot block tables ``(B, max_pages)`` instead of a
+        ``(L, B, S+1, ...)`` slot-dense tensor.  New K/V for
         position ``p`` of slot ``b`` scatters to flat physical row
         ``block_tables[b, p // ps] * ps + p % ps``; padding positions scatter
         into the last physical page (the sink, which block tables also use as
         their pad value) and are never read back (``kv_lens`` masks them).
 
         ``kv_layout="fused"`` stores the pool head-interleaved
-        (``kv_pages["kv"]: (L, n_phys, ps, 2*Hkv, hd)``, heads
+        (``kv_pages["kv"]: (L, n_phys, ps, 2*Hkv*hd)``, heads
         ``[K0,V0,K1,V1,...]``): the round's new K/V interleave into ONE
         scatter per layer and the attention kernel fetches each page's K+V
         with one DMA.  ``buffering_depth`` gathers run ahead of the kernels'
@@ -338,8 +340,8 @@ class TransformerLM:
         x = constrain(x, ("batch", "seq", "embed"))
 
         def scatter(pages, new):
-            return pages.reshape(n_phys * ps, *pages.shape[2:]).at[
-                write_pos].set(new).reshape(pages.shape)
+            return pages.reshape(n_phys * ps, -1).at[write_pos].set(
+                new.reshape(B, C, -1)).reshape(pages.shape)
 
         def body(carry, xs):
             h = L.rms_norm(carry, xs[0]["attn_norm"], cfg.norm_eps)
@@ -351,7 +353,7 @@ class TransformerLM:
             k_new = jnp.where(write_mask[:, :, None, None], k_new, 0)
             v_new = jnp.where(write_mask[:, :, None, None], v_new, 0)
             if fused:
-                lp, ckv = xs                   # (n_phys, ps, 2*Hkv, hd)
+                lp, ckv = xs                   # (n_phys, ps, 2*Hkv*hd)
                 Hkv, hd = k_new.shape[2], k_new.shape[3]
                 # interleave onto the head axis: ONE scatter writes K and V
                 kv_new = jnp.stack([k_new, v_new], axis=3).reshape(
@@ -371,7 +373,7 @@ class TransformerLM:
                     )
                 new_pages = (ckv,)
             else:
-                lp, ck, cv = xs                # (n_phys, ps, Hkv, hd)
+                lp, ck, cv = xs                # (n_phys, ps, Hkv*hd)
                 ck = scatter(ck, k_new)
                 cv = scatter(cv, v_new)
                 if C == 1:

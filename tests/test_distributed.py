@@ -171,7 +171,6 @@ def test_quantization_is_unbiased(rng):
 
 def test_compressed_psum_single_device():
     """axis of size 1: compressed psum == identity up to quantization."""
-    from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("dp",))
     grads = {"w": jnp.linspace(-1, 1, 512).reshape(2, 256)}
 
@@ -179,7 +178,7 @@ def test_compressed_psum_single_device():
         out, err = compressed_psum(g, "dp", jax.random.PRNGKey(0))
         return out, err
 
-    fm = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()))
+    fm = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()))
     out, err = fm(grads)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(grads["w"]),
                                atol=2e-2)

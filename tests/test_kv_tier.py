@@ -618,6 +618,24 @@ def test_q8_pallas_gather_matches_oracle(rng, layout, H):
     np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_o), rtol=1e-6)
 
 
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_q8_gather_flat_pool_needs_head_dim(rng, use_pallas):
+    """A flat ``(L, P, page_size, H*hd)`` pool scales per head only when
+    told ``head_dim``; without it the gather refuses instead of scaling
+    per page row."""
+    pages = jnp.asarray(rng.standard_normal((2, 9, 8, 2, 4)), jnp.float32)
+    flat = pages.reshape(2, 9, 8, 8)
+    ids = jnp.asarray([7, 2, 5], jnp.int32)
+    with pytest.raises(ValueError, match="head_dim"):
+        swap_gather_pages_q8(flat, ids, use_pallas=use_pallas,
+                             interpret=True)
+    q_f, s_f = swap_gather_pages_q8(flat, ids, head_dim=4,
+                                    use_pallas=use_pallas, interpret=True)
+    q_o, s_o = quantize_pages(pages[:, ids])
+    assert (np.asarray(q_f) == np.asarray(q_o)).all()
+    np.testing.assert_allclose(np.asarray(s_f), np.asarray(s_o), rtol=1e-6)
+
+
 @pytest.mark.parametrize("layout,H", _SHAPES, ids=["split", "fused"])
 def test_q8_pallas_scatter_matches_oracle(rng, layout, H):
     pages = jnp.asarray(rng.standard_normal((2, 9, 8, H, 4)), jnp.float32)

@@ -60,6 +60,7 @@ def _dense_view(pages, block_tables):
         (3, 8, 2, 64, 16, 6),      # GQA g=4
         (2, 8, 1, 32, 32, 4),      # MQA, bigger page
         (4, 16, 4, 16, 16, 5),     # engine tiny-config head_dim
+        (2, 8, 4, 64, 16, 5),      # two 128-lane blocks of two heads
     ],
 )
 def test_paged_decode_vs_dense_reference(rng, dtype, tol, B, Hq, Hkv, hd,
@@ -259,6 +260,35 @@ def test_paged_prefill_fused_layout(rng, depth):
                                atol=TOL_F32, rtol=TOL_F32)
 
 
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_fused_layout_many_lane_blocks(rng, kernel):
+    """At head_dim 64 a fused page row splits into one 128-lane block per
+    K+V head pair (four here, and two split): every block's offset and its
+    K/V lanes must land on the right heads."""
+    B, Sq, Hq, Hkv, hd, ps, mp = 2, 32, 8, 4, 64, 16, 5
+    k_pages, v_pages, bt = _paged_setup(rng, B, Hkv, hd, ps, mp, jnp.float32)
+    kv_pages = ref.fuse_pages(k_pages, v_pages)
+    if kernel == "decode":
+        q = _rand(rng, (B, Hq, hd), jnp.float32)
+        kv_lens = jnp.asarray([ps + 3, mp * ps], jnp.int32)
+        out = paged_decode_attention_fused(q, kv_pages, bt, kv_lens)
+        split = paged_decode_attention(q, k_pages, v_pages, bt, kv_lens)
+        want = ref.paged_decode_attention_fused_ref(q, kv_pages, bt, kv_lens)
+    else:
+        q = _rand(rng, (B, Sq, Hq, hd), jnp.float32)
+        q_off = jnp.asarray([7, mp * ps - Sq], jnp.int32)
+        kv_lens = q_off + Sq
+        out = paged_prefill_attention_fused(q, kv_pages, bt, kv_lens, q_off,
+                                            block_q=16)
+        split = paged_prefill_attention(q, k_pages, v_pages, bt, kv_lens,
+                                        q_off, block_q=16)
+        want = ref.paged_prefill_attention_fused_ref(q, kv_pages, bt, kv_lens,
+                                                     q_off)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(split), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=TOL_F32, rtol=TOL_F32)
+
+
 # ---------------------------------------------------------------------------
 # paged chunked-prefill
 # ---------------------------------------------------------------------------
@@ -272,6 +302,7 @@ def test_paged_prefill_fused_layout(rng, depth):
         (2, 64, 8, 2, 64, 16, 8, 32),     # GQA g=4
         (1, 16, 8, 1, 32, 32, 3, 16),     # MQA
         (3, 32, 16, 4, 16, 16, 4, 32),    # engine tiny-config head_dim
+        (2, 32, 8, 4, 64, 16, 5, 16),     # two 128-lane blocks of two heads
     ],
 )
 def test_paged_prefill_vs_dense_reference(rng, dtype, tol, B, Sq, Hq, Hkv, hd,

@@ -1,0 +1,114 @@
+"""Compile-only rehearsal of the serving kernels for a TPU v5e chip.
+
+The TPU compiler compiles for a chip that is described, not attached, so
+these tests catch what interpret mode cannot — Mosaic's tiling and lane
+alignment rules, VMEM limits — with no chip.  Shapes are Qwen1.5-0.5B's
+serving widths (16 query and 16 kv heads of head_dim 64, 16-token pages,
+a 2,049-page pool, 16 batch rows, 24 layers for the swap kernels) in the
+flat page layout the engine stores.  Each test asserts the kernel was
+compiled by Mosaic (``tpu_custom_call``), not interpreted.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_decode_attention import (
+    paged_decode_attention,
+    paged_decode_attention_fused,
+)
+from repro.kernels.paged_prefill_attention import (
+    paged_prefill_attention,
+    paged_prefill_attention_fused,
+)
+from repro.kernels.swap import (
+    swap_gather_pages,
+    swap_gather_pages_q8,
+    swap_scatter_pages,
+    swap_scatter_pages_q8,
+)
+
+B, HQ, HKV, HD, PS, N_PAGES, MAX_PAGES, LAYERS = 16, 16, 16, 64, 16, 2049, 33, 24
+LANES = HKV * HD
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A described v5e chip, with the persistent compile cache off: entries
+    written for a chip that is not attached cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compiled_text(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+_BF16, _I32 = jnp.bfloat16, jnp.int32
+_TABLES = [((B, MAX_PAGES), _I32), ((B,), _I32)]
+
+
+def _attention_case(kernel, C, fused):
+    pools = [((N_PAGES, PS, 2 * LANES), _BF16)] if fused else \
+        [((N_PAGES, PS, LANES), _BF16)] * 2
+    if C == 1:
+        q = [((B, HQ, HD), _BF16)]
+        return (lambda *a: kernel(*a, interpret=False)), q + pools + _TABLES
+    q = [((B, C, HQ, HD), _BF16)]
+    return ((lambda *a: kernel(*a, interpret=False)),
+            q + pools + _TABLES + [((B,), _I32)])
+
+
+@pytest.mark.parametrize("layout", ["split", "fused"])
+@pytest.mark.parametrize("C", [1, 16, 256], ids=["decode", "prefill16",
+                                                 "prefill256"])
+def test_paged_attention_compiles_for_v5e(one_chip, layout, C):
+    fused = layout == "fused"
+    if C == 1:
+        kernel = paged_decode_attention_fused if fused else paged_decode_attention
+    else:
+        kernel = paged_prefill_attention_fused if fused else paged_prefill_attention
+    fn, shapes = _attention_case(kernel, C, fused)
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+_POOL = ((LAYERS, N_PAGES, PS, LANES), _BF16)
+_IDS = ((32,), _I32)
+_SWAP_CASES = {
+    "gather": (lambda p, i: swap_gather_pages(
+        p, i, use_pallas=True, interpret=False), [_POOL, _IDS]),
+    "scatter": (lambda p, i, s: swap_scatter_pages(
+        p, i, s, use_pallas=True, interpret=False),
+        [_POOL, _IDS, ((LAYERS, 32, PS, LANES), _BF16)]),
+    "gather_q8": (lambda p, i: swap_gather_pages_q8(
+        p, i, head_dim=HD, use_pallas=True, interpret=False), [_POOL, _IDS]),
+    "scatter_q8": (lambda p, i, q, s: swap_scatter_pages_q8(
+        p, i, q, s, use_pallas=True, interpret=False),
+        [_POOL, _IDS, ((LAYERS, 32, PS, HKV, HD), jnp.int8),
+         ((LAYERS, 32, 1, HKV, 1), jnp.float32)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SWAP_CASES))
+def test_swap_kernels_compile_for_v5e(one_chip, case):
+    fn, shapes = _SWAP_CASES[case]
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
