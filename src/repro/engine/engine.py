@@ -59,6 +59,7 @@ from repro.kernels.ops import (
     scatter_swap_pages_q8,
 )
 from repro.engine.sampler import SamplerConfig, sample_tokens
+from repro.engine.trace import Recorder, host_bubbles_ms
 from repro.models.model import Model, build_model
 from repro.robustness import FailoverStats, ReplicaHealth
 
@@ -149,10 +150,8 @@ class JAXEngine:
         self.slot_of: Dict[int, int] = {}          # req_id -> slot
         self.free_slots = list(range(B - 1, -1, -1))
 
-        # device-idle gap before each dispatch (the host bubble the pipeline
-        # is built to close); fed by execute()/dispatch()
-        self.bubble_ms: List[float] = []
-        self._t_ready: Optional[float] = None
+        # host spans and round counters; a ReplicaServer hands in its own
+        self.trace = Recorder()
         # nan_guard: req_ids whose sampled logits were non-finite in the most
         # recently drained round (sync serve loops read it after execute())
         self.last_nonfinite: set = set()
@@ -218,12 +217,13 @@ class JAXEngine:
                         sample_mask, rng):
             """Sampling + length update + device token feedback, fused into
             the SAME dispatch as the forward pass (no follow-up host ops)."""
-            toks = sample_tokens(logits, rng, self.cfg.sampler)
-            new_last = jnp.where(sample_mask, toks, last_token)
-            if cfg.nan_guard:
-                finite = jnp.isfinite(logits).all(axis=-1)
-                return toks, cache, lens + chunk_lens, new_last, finite
-            return toks, cache, lens + chunk_lens, new_last
+            with jax.named_scope("sample"):
+                toks = sample_tokens(logits, rng, self.cfg.sampler)
+                new_last = jnp.where(sample_mask, toks, last_token)
+                if cfg.nan_guard:
+                    finite = jnp.isfinite(logits).all(axis=-1)
+                    return toks, cache, lens + chunk_lens, new_last, finite
+                return toks, cache, lens + chunk_lens, new_last
 
         if cfg.paged_kv:
             bs = self.kv_pool.cfg.block_size
@@ -481,6 +481,10 @@ class JAXEngine:
         the copy — the same one-round-late visibility the token readback
         has, so a mid-pipeline victim is never restored (or re-bound) in the
         round that is still copying its pages out."""
+        with self.trace.span("swap_out", req.req_id):
+            self._swap_out(req)
+
+    def _swap_out(self, req: Request) -> None:
         pool = self.kv_pool
         slot = self.slot_of.get(req.req_id)
         assert slot is not None, f"swap_out of unbound req {req.req_id}"
@@ -541,6 +545,10 @@ class JAXEngine:
         rebuilt the request's table from fresh blocks: scatter the staged
         K/V into the new physical pages (paged) or the freshly bound slot's
         rows (dense) and restore the device-side length."""
+        with self.trace.span("swap_in", req.req_id):
+            self._swap_in(req, payload)
+
+    def _swap_in(self, req: Request, payload) -> None:
         slot = self.slot_of.get(req.req_id)
         assert slot is not None, f"swap_in of unbound req {req.req_id}"
         assert payload is not None, f"swap_in of req {req.req_id} without payload"
@@ -800,6 +808,11 @@ class JAXEngine:
             [c for _, c in batch.prefill_chunks] + [1 if batch.decode_reqs else 0]
         )
         C = self._bucket(max_chunk)
+        if self.trace.on:
+            n_decode = len(batch.decode_reqs)
+            self.trace.count(
+                sum(c for _, c in batch.prefill_chunks) + n_decode, B * C,
+                len(batch.prefill_chunks) + n_decode, C)
         tokens = np.zeros((B, C), np.int32)
         chunk_lens = np.zeros((B,), np.int32)
         use_last = np.zeros((B,), np.bool_)
@@ -837,23 +850,28 @@ class JAXEngine:
         (forward + sampling + length update, one dispatch) runs while the
         caller goes back to scheduling.  The sampled-token readback starts as
         an async device->host copy; ``drain`` collects it one round later."""
-        tokens, chunk_lens, use_last, sample_mask, sampled = self._stage(batch)
-        args = (self.params, self._put(tokens), self.cache, self.lens,
-                self._put(chunk_lens))
-        if self.cfg.paged_kv:
-            self._sync_block_tables(batch)
-            args += (self.block_tables,)
-        args += (self.last_token, self._put(use_last), self._put(sample_mask))
-        self._rng, sub = jax.random.split(self._rng)
-        t_dispatch = time.perf_counter()
-        if self._t_ready is not None:
-            self.bubble_ms.append((t_dispatch - self._t_ready) * 1e3)
-        out = self._step(*args, sub)
-        toks, self.cache, self.lens, self.last_token = out[:4]
-        finite = out[4] if len(out) > 4 else None
-        toks.copy_to_host_async()
-        if finite is not None:
-            finite.copy_to_host_async()
+        tr = self.trace
+        with tr.span("dispatch"):
+            with tr.span("stage"):
+                tokens, chunk_lens, use_last, sample_mask, sampled = (
+                    self._stage(batch))
+            args = (self.params, self._put(tokens), self.cache, self.lens,
+                    self._put(chunk_lens))
+            if self.cfg.paged_kv:
+                with tr.span("block_tables"):
+                    self._sync_block_tables(batch)
+                args += (self.block_tables,)
+            args += (self.last_token, self._put(use_last),
+                     self._put(sample_mask))
+            self._rng, sub = jax.random.split(self._rng)
+            t_dispatch = time.perf_counter()
+            with tr.span("launch"):
+                out = self._step(*args, sub)
+                toks, self.cache, self.lens, self.last_token = out[:4]
+                finite = out[4] if len(out) > 4 else None
+                toks.copy_to_host_async()
+                if finite is not None:
+                    finite.copy_to_host_async()
         return InflightRound(toks=toks, sampled=sampled, t_dispatch=t_dispatch,
                              finite=finite)
 
@@ -865,27 +883,30 @@ class JAXEngine:
         preemption already folded into a recompute prompt.  Returns
         dispatch->drain wall ms (device time plus whatever host work it
         overlapped)."""
-        toks = np.asarray(inflight.toks)
-        self._t_ready = time.perf_counter()
-        wall_ms = (self._t_ready - inflight.t_dispatch) * 1e3
-        if inflight.finite is not None:
-            fin = np.asarray(inflight.finite)
-            inflight.nonfinite = {
-                req.req_id for req, slot in inflight.sampled if not fin[slot]
-            }
-        # sync-mode mirror (execute() discards the InflightRound): the serve
-        # loop reads the quarantine set of the round it just executed here
-        self.last_nonfinite = inflight.nonfinite
-        # swap-out staging retires on the same one-round-late path: gathers
-        # dispatched before this round's step are host-side by now (or the
-        # asarray below bounds the wait)
-        self.finalize_swaps()
-        for req, slot in inflight.sampled:
-            tok = int(toks[slot])
-            req.next_token = tok
-            idx = inflight.out_index.get(req.req_id)
-            if idx is not None:
-                req.patch_token(idx, tok)
+        with self.trace.span("drain.wait"):
+            toks = np.asarray(inflight.toks)
+        wall_ms = (time.perf_counter() - inflight.t_dispatch) * 1e3
+        with self.trace.span("drain.deliver"):
+            if inflight.finite is not None:
+                fin = np.asarray(inflight.finite)
+                inflight.nonfinite = {
+                    req.req_id for req, slot in inflight.sampled
+                    if not fin[slot]
+                }
+            # sync-mode mirror (execute() discards the InflightRound): the
+            # serve loop reads the quarantine set of the round it just
+            # executed here
+            self.last_nonfinite = inflight.nonfinite
+            # swap-out staging retires on the same one-round-late path:
+            # gathers dispatched before this round's step are host-side by
+            # now (or the asarray below bounds the wait)
+            self.finalize_swaps()
+            for req, slot in inflight.sampled:
+                tok = int(toks[slot])
+                req.next_token = tok
+                idx = inflight.out_index.get(req.req_id)
+                if idx is not None:
+                    req.patch_token(idx, tok)
         return wall_ms
 
     def execute(self, batch: ScheduledBatch) -> float:
@@ -906,7 +927,8 @@ class ServeResult:
     samples: Optional[Tuple[np.ndarray, np.ndarray]] = None
     outputs: Optional[Dict[int, List[int]]] = None
     memory: Optional[MemoryReport] = None     # KV pool lifecycle summary
-    host_bubble_ms: Optional[List[float]] = None   # device-idle gap per round
+    # per round, drain.wait end -> launch start (trace.host_bubbles_ms)
+    host_bubble_ms: Optional[List[float]] = None
     slo: Optional[SLOReport] = None           # per-tenant attainment gauges
     robustness: Optional["RobustnessReport"] = None  # chaos/fault summary
 
@@ -1017,11 +1039,9 @@ class ReplicaServer:
                 restorer_tail=engine.swap_in_tail,
                 payload_slicer=engine.slice_swap_payload,
             )
-        # bubble accounting is per-serve: drop any history (and the
-        # ready-stamp of a previous serve, which would read as one giant
-        # inter-serve bubble)
-        engine.bubble_ms = []
-        engine._t_ready = None
+        # this replica's spans and counters, recorded by its engine too
+        self.trace = Recorder()
+        engine.trace = self.trace
 
     # -- clock ----------------------------------------------------------------
     def start(self, t_start: float) -> None:
@@ -1110,7 +1130,22 @@ class ReplicaServer:
             return "error"
 
     def _step_impl(self, now: float) -> str:
-        sched, engine = self.sched, self.engine
+        """One step; with recording on, a step that runs a round is the
+        ``round`` span (the profiler's ``round`` annotation covers every
+        step)."""
+        tr = self.trace
+        tr.round_id = self.rounds
+        if not tr.on:
+            return self._step_body(now)
+        t0 = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation("round"):
+            status = self._step_body(now)
+        if status == "round":
+            tr.add("round", t0, time.perf_counter_ns())
+        return status
+
+    def _step_body(self, now: float) -> str:
+        sched, engine, tr = self.sched, self.engine, self.trace
         drained_eagerly = False
         if self.inflight is not None and self.inflight.toks.is_ready():
             # device already finished: drain before (not after) the next
@@ -1126,7 +1161,8 @@ class ReplicaServer:
                 # an exported (handoff) request's gather can be the only
                 # pending work on this replica — land it so the router can
                 # move the staged record on
-                engine.finalize_swaps()
+                with tr.span("finalize_swaps"):
+                    engine.finalize_swaps()
                 return "finalized"
             # an eager drain above counts as progress — it may have just
             # finalized an exported gather the router is waiting on, so
@@ -1137,7 +1173,15 @@ class ReplicaServer:
         # releaser hook) — a victim may even have re-bound a fresh slot and
         # been rescheduled within the same round, so do NOT release here.
         # In pipelined mode this schedule overlaps the in-flight round.
-        batch = sched.schedule(now)
+        with tr.span("schedule"):
+            batch = sched.schedule(now)
+        if tr.on:
+            # a request's first scheduled chunk ends its wait in the queue
+            t_sched = time.perf_counter_ns()
+            for r, _c in batch.prefill_chunks:
+                if not r.chunks:
+                    tr.add("queued", int((self.t_start + r.arrival_time) * 1e9),
+                           t_sched, r.req_id)
         if batch.is_empty():
             if self.inflight is not None:
                 self._drain_inflight()
@@ -1146,7 +1190,8 @@ class ReplicaServer:
                 # nothing in flight to piggyback the staging drain on (e.g.
                 # every runnable request is a SWAPPING victim): finalize now
                 # so the next schedule() round can restore them
-                engine.finalize_swaps()
+                with tr.span("finalize_swaps"):
+                    engine.finalize_swaps()
                 return "finalized"
             return "drained" if drained_eagerly else "starved"
 
@@ -1174,8 +1219,9 @@ class ReplicaServer:
             wall_ms = engine.execute(batch)
         if self.kv_pool is not None:
             # newly sealed (full, hashed) prompt blocks become restorable
-            for r, _c in batch.prefill_chunks:
-                engine.capture_sealed(r)
+            with tr.span("capture_sealed"):
+                for r, _c in batch.prefill_chunks:
+                    engine.capture_sealed(r)
         if self.collect_samples:
             self.feats.append(batch.state.features())
             if wall_ms is not None:
@@ -1183,7 +1229,8 @@ class ReplicaServer:
         self.rounds += 1
 
         now2 = self._now()
-        sched.on_batch_done(batch, now2)       # releases finished KV refs
+        with tr.span("on_batch_done"):
+            sched.on_batch_done(batch, now2)   # releases finished KV refs
         self._pending_batch = None             # retired: charged and counted
 
         # sync-mode numerics quarantine: execute() drained inside the round,
@@ -1257,6 +1304,13 @@ class ReplicaServer:
         wall_ms = self.engine.drain(inflight)
         if self.collect_samples:
             self.lats.append(wall_ms)
+        with self.trace.span("drain.deliver"):
+            self._deliver(inflight, pending_batch)
+        self._draining = None
+
+    def _deliver(self, inflight: InflightRound,
+                 pending_batch: Optional[ScheduledBatch]) -> None:
+        """Stamp, deliver and stop the drained round's requests."""
         # timestamps recorded against the placeholder `now` are re-stamped to
         # the moment the ids actually became host-visible — the earliest a
         # client could receive them — so pipelined LatencyReports are not
@@ -1311,7 +1365,6 @@ class ReplicaServer:
             self.sched.on_stop(req, pending_batch)
             if self.on_stopped is not None:
                 self.on_stopped(self, req)
-        self._draining = None
 
     # -- crash unwind ----------------------------------------------------------
     def _crash_cleanup(self) -> None:
@@ -1410,7 +1463,8 @@ class ReplicaServer:
         swap copies (no staging entry is left mid-flight at exit)."""
         if self.inflight is not None:
             self._drain_inflight()
-        self.engine.finalize_swaps()
+        with self.trace.span("finalize_swaps"):
+            self.engine.finalize_swaps()
 
 
 def serve(
@@ -1455,6 +1509,7 @@ def serve(
     server = ReplicaServer(
         scheduler, engine, kv_pool=kv_pool, collect_samples=collect_samples,
     )
+    server.trace.on = True          # host_bubble_ms reads the spans
     if robustness is not None:
         # colocated fault tolerance: crash unwinds + NaN quarantine survive
         # in-place (there is no second replica to fail over to — replica
@@ -1527,7 +1582,7 @@ def serve(
         memory=(
             summarize_memory(kv_pool, scheduler.stats) if kv_pool is not None else None
         ),
-        host_bubble_ms=list(engine.bubble_ms),
+        host_bubble_ms=host_bubbles_ms(server.trace.spans),
         slo=(
             summarize_slo(requests, scheduler.fairness.registry)
             if scheduler.fairness is not None else None

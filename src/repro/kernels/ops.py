@@ -11,6 +11,10 @@ the former.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
+
 from repro.kernels import ref
 from repro.kernels.chunked_prefill_attention import chunked_prefill_attention
 from repro.kernels.decode_attention import decode_attention
@@ -29,11 +33,23 @@ from repro.kernels.swap import (
 )
 
 
+def _attention(fn):
+    """Run ``fn`` under the ``attention`` name scope: every device op of the
+    oracle or the kernel carries it in its metadata (the compiled program is
+    unchanged)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("attention"):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 def _heads(pages, head_dim):
     """A paged pool as ``(n_pages, page_size, H, hd)`` for the oracles."""
     return pages.reshape(pages.shape[:2] + (-1, head_dim))
 
 
+@_attention
 def prefill_chunk_attention(q, k_cache, v_cache, kv_lens, q_offset, *,
                             use_pallas: bool = True, block_q: int = 128,
                             block_k: int = 128):
@@ -46,6 +62,7 @@ def prefill_chunk_attention(q, k_cache, v_cache, kv_lens, q_offset, *,
     )
 
 
+@_attention
 def flash_decode_attention(q, k_cache, v_cache, kv_lens, *,
                            use_pallas: bool = True, block_k: int = 256):
     """(B, Hq, hd) single-token decode vs (B, S, Hkv, hd) cache."""
@@ -56,6 +73,7 @@ def flash_decode_attention(q, k_cache, v_cache, kv_lens, *,
     )
 
 
+@_attention
 def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables, kv_lens,
                                   q_offset, *, use_pallas: bool = True,
                                   block_q: int = 128, pages_per_tile: int = 1,
@@ -77,6 +95,7 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables, kv_lens,
     )
 
 
+@_attention
 def paged_prefill_chunk_attention_fused(q, kv_pages, block_tables, kv_lens,
                                         q_offset, *, use_pallas: bool = True,
                                         block_q: int = 128,
@@ -94,6 +113,7 @@ def paged_prefill_chunk_attention_fused(q, kv_pages, block_tables, kv_lens,
     )
 
 
+@_attention
 def paged_flash_decode_attention(q, k_pages, v_pages, block_tables, kv_lens, *,
                                  use_pallas: bool = True,
                                  pages_per_tile: int = 1,
@@ -109,6 +129,7 @@ def paged_flash_decode_attention(q, k_pages, v_pages, block_tables, kv_lens, *,
     )
 
 
+@_attention
 def paged_flash_decode_attention_fused(q, kv_pages, block_tables, kv_lens, *,
                                        use_pallas: bool = True,
                                        pages_per_tile: int = 1,

@@ -315,32 +315,34 @@ def init_attention(rng, cfg, d_model: Optional[int] = None, cross: bool = False)
 
 def qkv_project(p, x, cfg, positions=None, rope: bool = True):
     """x: (B, S, D) -> q (B,S,Hq,hd), k/v (B,S,Hkv,hd) with optional RoPE."""
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if "bq" in p:
-        q = q + p["bq"]
-        k = k + p["bk"]
-        v = v + p["bv"]
-    q = constrain(q, ("batch", "seq", "heads", None))
-    k = constrain(k, ("batch", "seq", "kv_heads", None))
-    v = constrain(v, ("batch", "seq", "kv_heads", None))
-    if rope and positions is not None:
-        # re-pin after rope: the roped outputs are new values, and an
-        # unpinned k lets GSPMD pull the prefill-cache layout into the
-        # attention loop (per-block all-gathers)
-        q = constrain(apply_rope(q, positions, cfg.rope_theta),
-                      ("batch", "seq", "heads", None))
-        k = constrain(apply_rope(k, positions, cfg.rope_theta),
-                      ("batch", "seq", "kv_heads", None))
-    return q, k, v
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+        if "bq" in p:
+            q = q + p["bq"]
+            k = k + p["bk"]
+            v = v + p["bv"]
+        q = constrain(q, ("batch", "seq", "heads", None))
+        k = constrain(k, ("batch", "seq", "kv_heads", None))
+        v = constrain(v, ("batch", "seq", "kv_heads", None))
+        if rope and positions is not None:
+            # re-pin after rope: the roped outputs are new values, and an
+            # unpinned k lets GSPMD pull the prefill-cache layout into the
+            # attention loop (per-block all-gathers)
+            q = constrain(apply_rope(q, positions, cfg.rope_theta),
+                          ("batch", "seq", "heads", None))
+            k = constrain(apply_rope(k, positions, cfg.rope_theta),
+                          ("batch", "seq", "kv_heads", None))
+        return q, k, v
 
 
 def attn_output(p, attn, cfg):
-    out = jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
-    # row-parallel output: under sequence parallelism (act_seq -> model) the
-    # partial sums reduce-scatter over S instead of all-reducing
-    return constrain(out, ("batch", "act_seq", "embed"))
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
+        # row-parallel output: under sequence parallelism (act_seq -> model)
+        # the partial sums reduce-scatter over S instead of all-reducing
+        return constrain(out, ("batch", "act_seq", "embed"))
 
 
 # ---------------------------------------------------------------------------

@@ -39,15 +39,16 @@ def _block_init(rng, cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def _block_ffn(p, x, cfg: ModelConfig):
-    h = L.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
-    if "moe" in p:
-        moe_fn = L.moe_ffn_scatter if cfg.moe_impl == "scatter" else L.moe_ffn
-        out = moe_fn(p["moe"], h, cfg)
-        if "ffn" in p:  # arctic dense residual (parallel branch)
-            out = out + L.ffn(p["ffn"], h)
-    else:
-        out = L.ffn(p["ffn"], h)
-    return x + out
+    with jax.named_scope("ffn"):
+        h = L.rms_norm(x, p["ffn_norm"], cfg.norm_eps)
+        if "moe" in p:
+            moe_fn = L.moe_ffn_scatter if cfg.moe_impl == "scatter" else L.moe_ffn
+            out = moe_fn(p["moe"], h, cfg)
+            if "ffn" in p:  # arctic dense residual (parallel branch)
+                out = out + L.ffn(p["ffn"], h)
+        else:
+            out = L.ffn(p["ffn"], h)
+        return x + out
 
 
 def _block_fwd(p, x, positions, cfg: ModelConfig, collect_kv: bool):
@@ -135,11 +136,10 @@ class TransformerLM:
         return constrain(x, ("batch", "seq", "embed"))
 
     def _unembed(self, params, x):
-        if "lm_head" in params:
-            logits = jnp.einsum("...d,dv->...v", x, params["lm_head"])
-        else:
-            logits = jnp.einsum("...d,vd->...v", x, params["embed"])
-        return logits
+        with jax.named_scope("unembed"):
+            if "lm_head" in params:
+                return jnp.einsum("...d,dv->...v", x, params["lm_head"])
+            return jnp.einsum("...d,vd->...v", x, params["embed"])
 
     def _run_layers(self, params, x, positions, collect_kv: bool, remat: bool):
         cfg = self.cfg
@@ -340,8 +340,9 @@ class TransformerLM:
         x = constrain(x, ("batch", "seq", "embed"))
 
         def scatter(pages, new):
-            return pages.reshape(n_phys * ps, -1).at[write_pos].set(
-                new.reshape(B, C, -1)).reshape(pages.shape)
+            with jax.named_scope("kv_write"):
+                return pages.reshape(n_phys * ps, -1).at[write_pos].set(
+                    new.reshape(B, C, -1)).reshape(pages.shape)
 
         def body(carry, xs):
             h = L.rms_norm(carry, xs[0]["attn_norm"], cfg.norm_eps)
@@ -393,16 +394,20 @@ class TransformerLM:
             y = _block_ffn(lp, y, cfg)
             return y, new_pages
 
-        if fused:
-            x, (nkv,) = jax.lax.scan(
-                body, x, (params["layers"], kv_pages["kv"])
-            )
-            new_cache = {"kv": nkv}
-        else:
-            x, (nk, nv) = jax.lax.scan(
-                body, x, (params["layers"], kv_pages["k"], kv_pages["v"])
-            )
-            new_cache = {"k": nk, "v": nv}
+        # layer_scan: the loop's own ops (each layer's weights and pages
+        # sliced in, its updated pages stacked out, norms and residuals);
+        # the scopes inside body name the rest
+        with jax.named_scope("layer_scan"):
+            if fused:
+                x, (nkv,) = jax.lax.scan(
+                    body, x, (params["layers"], kv_pages["kv"])
+                )
+                new_cache = {"kv": nkv}
+            else:
+                x, (nk, nv) = jax.lax.scan(
+                    body, x, (params["layers"], kv_pages["k"], kv_pages["v"])
+                )
+                new_cache = {"k": nk, "v": nv}
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         last = jnp.maximum(chunk_lens - 1, 0)
         x_last = x[bidx, last]                       # (B, D)
