@@ -15,12 +15,14 @@ Continuous batching with PAGED KV storage (vLLM layout, the default):
     still resident); the last page is a write sink for padding lanes.
   * One jitted step per scheduling round executes the ENTIRE mixed batch —
     decode slots advance by 1 token, prefill slots by their scheduled chunk,
-    idle slots by 0 — under static bucketed shapes.  The step FUSES the
-    cache-length update and token sampling (one dispatch per round, no
-    follow-up host ops) and keeps the sampled tokens in a device-resident
-    ``last_token`` buffer that the NEXT round's step consumes directly, so
-    decode can proceed round-to-round without the host ever observing the
-    token values.
+    idle slots by 0 — under static bucketed shapes; where it is cheaper
+    (``SPLIT_ROW_COST``), a round with prefill chunks runs as one decode row
+    per slot plus one row per chunk, not every slot padded to the widest
+    chunk (``JAXEngine._stage``).  The step FUSES the cache-length update
+    and token sampling (one dispatch per round, no follow-up host ops) and
+    keeps the sampled tokens in a device-resident ``last_token`` buffer that
+    the NEXT round's step consumes directly, so decode can proceed
+    round-to-round without the host ever observing the token values.
   * PIPELINED serving (``EngineConfig(pipelined=True)``, the default):
     ``serve`` overlaps round N's device execution with the host's
     scheduling/aging/VTC/KV booking for round N+1.  The host readback of
@@ -62,6 +64,17 @@ from repro.engine.sampler import SamplerConfig, sample_tokens
 from repro.engine.trace import Recorder, host_bubbles_ms
 from repro.models.model import Model, build_model
 from repro.robustness import FailoverStats, ReplicaHealth
+
+# What one decode row of a split round costs, in query positions of the
+# padded round's prefill attention, per attention path.  A round with
+# prefill chunks at bucket C runs split -- one decode row per slot plus P
+# rows of C tokens -- only where the padding it drops costs more than the
+# decode rows it adds: (n_slots - P) * C > SPLIT_ROW_COST * n_slots.  From
+# step times on TPU v5e, 16 slots of 4k context (PERF.md section 5): the
+# oracle's decode rows pay float32 copies of the gathered K/V (any cost
+# from 128 to 191 picks the faster kind at every shape timed); with the
+# Pallas kernels the split was faster at every shape timed.
+SPLIT_ROW_COST = {"oracle": 160, "pallas": 0}
 
 
 @dataclass
@@ -152,6 +165,14 @@ class JAXEngine:
 
         # host spans and round counters; a ReplicaServer hands in its own
         self.trace = Recorder()
+        # (C, P) of the split rounds: P prefill rows, a power of two under
+        # the slot count, where the padding dropped outweighs the decode
+        # rows added (SPLIT_ROW_COST); the dense path always runs padded
+        row_cost = SPLIT_ROW_COST["pallas" if self.cfg.use_pallas else "oracle"]
+        self._split_shapes = frozenset(
+            (C, P) for C in self.cfg.chunk_buckets if C > 1
+            for P in (1 << i for i in range(B.bit_length()))
+            if self.cfg.paged_kv and (B - P) * C > row_cost * B)
         # nan_guard: req_ids whose sampled logits were non-finite in the most
         # recently drained round (sync serve loops read it after execute())
         self.last_nonfinite: set = set()
@@ -243,10 +264,12 @@ class JAXEngine:
             self.block_tables = self._put(self._bt_host)
 
             def step(params, tokens, cache, lens, chunk_lens, block_tables,
-                     last_token, use_last, sample_mask, rng):
+                     last_token, use_last, sample_mask, rng,
+                     pre_tokens=None, pre_slot=None):
                 tokens = _inject_last(tokens, use_last, last_token)
                 logits, cache = impl.chunked_step_paged(
                     params, tokens, cache, lens, chunk_lens, block_tables,
+                    pre_tokens, pre_slot,
                     use_pallas=use_pallas, pages_per_tile=pages_per_tile,
                     kv_layout=cfg.kv_layout,
                     buffering_depth=cfg.buffering_depth,
@@ -315,9 +338,9 @@ class JAXEngine:
 
         Every jitted shape the serving loop can hit under the CONFIGURED
         ``(kv_layout, buffering_depth, pages_per_tile)`` combination is
-        covered: the step compiles per chunk bucket with those knobs baked
-        in, the dirty-row block-table scatter per power-of-two row bucket,
-        and — when this engine can swap (``preemption_mode="swap"``) or the
+        covered: the step compiles per ``round_shapes()`` entry with those
+        knobs baked in, the dirty-row block-table scatter per power-of-two
+        row bucket, and — when this engine can swap (``preemption_mode="swap"``) or the
         caller says it will export/import KV (``include_swap=True``, the
         disagg handoff path, which rides the same gather/scatter kernels
         regardless of preemption mode) — the swap kernels per page-id
@@ -328,9 +351,9 @@ class JAXEngine:
         which changes the cache shape and invalidates everything compiled
         here — bind first, then warm up."""
         B = self.cfg.n_slots
-        for C in self.cfg.chunk_buckets:
+        for C, P in self.round_shapes():
             self._rng, sub = jax.random.split(self._rng)
-            out = self._step(*self._dummy_round(C, sub))
+            out = self._step(*self._dummy_round(C, sub, P))
             toks, self.cache, self.lens, self.last_token = out[:4]
             jax.block_until_ready(toks)
         # reset cache/lens state touched by the dummy rounds (paged writes all
@@ -351,25 +374,37 @@ class JAXEngine:
             self._prewarm_swap_shapes()
         self.warmed = True
 
-    def _dummy_round(self, C: int, rng):
-        """Step arguments of a throwaway round at chunk bucket ``C``: slot 0
-        takes one token (paged: into the sink page), every other slot idles
-        and no sampled token is kept."""
+    def round_shapes(self) -> List[Tuple[int, int]]:
+        """``(C, P)`` of every step shape a round can take: ``P = 0`` is one
+        row of ``C`` tokens per slot (decode-only rounds at ``C = 1``),
+        ``P > 0`` a split round of one decode row per slot and ``P`` prefill
+        rows of ``C`` tokens."""
+        return sorted({(C, 0) for C in self.cfg.chunk_buckets}
+                      | self._split_shapes)
+
+    def _dummy_round(self, C: int, rng, P: int = 0):
+        """Step arguments of a throwaway round of shape ``(C, P)``: slot 0
+        takes one token (paged: into the sink page), every other slot and
+        every prefill row idles, and no sampled token is kept."""
         B = self.cfg.n_slots
         off = self._put(np.zeros((B,), np.bool_))
         chunk_lens = np.zeros((B,), np.int32)
         chunk_lens[0] = 1
-        args = (self.params, self._put(np.ones((B, C), np.int32)), self.cache,
-                self.lens, self._put(chunk_lens))
+        args = (self.params, self._put(np.ones((B, 1 if P else C), np.int32)),
+                self.cache, self.lens, self._put(chunk_lens))
         if self.cfg.paged_kv:
             args += (self.block_tables,)
-        return args + (self.last_token, off, off, rng)
+        args += (self.last_token, off, off, rng)
+        if P:
+            args += (self._put(np.ones((P, C), np.int32)),
+                     self._put(np.full((P,), B, np.int32)))
+        return args
 
-    def step_hlo(self, C: int) -> str:
-        """Compiled HLO text of the round step at chunk bucket ``C``.  On a
+    def step_hlo(self, C: int, P: int = 0) -> str:
+        """Compiled HLO text of the round step of shape ``(C, P)``.  On a
         TPU, Mosaic-compiled Pallas kernels show up as ``tpu_custom_call``;
         interpreted ones would not."""
-        args = self._dummy_round(C, self._rng)
+        args = self._dummy_round(C, self._rng, P)
         return self._step.lower(*args).compile().as_text()
 
     def _prewarm_swap_shapes(self) -> None:
@@ -796,24 +831,36 @@ class JAXEngine:
             )
             self._bt_dirty.clear()
 
+    def _split_rows(self, C: int, n: int) -> int:
+        """Prefill rows ``P`` of a round of ``n`` chunks at bucket ``C``: the
+        power of two that holds them, or 0 where ``(C, P)`` is not a split
+        shape and the round runs padded."""
+        P = 1 << max(n - 1, 0).bit_length()
+        return P if (C, P) in self._split_shapes else 0
+
     def _stage(self, batch: ScheduledBatch):
         """Host-side staging for one round: token ids (int32 — half the
         host->device width of the seed engine's int64 staging), per-slot
         chunk lengths, and the two masks the fused step needs: which slots
         consume the device-resident ``last_token`` (decodes) and which slots'
         sampled token is meaningful this round (decodes + chunks that finish
-        their prefill)."""
+        their prefill).
+
+        A round whose widest chunk takes bucket ``C`` runs split where
+        ``_split_rows`` gives it ``P > 0`` prefill rows: ``(B, 1)`` decode
+        rows plus ``pre = (tokens (P, C), slot of each row)``, rows past the
+        chunks pointing at slot ``B`` (masked).  Otherwise every slot is one
+        row of ``C`` tokens and ``pre`` is empty."""
         B = self.cfg.n_slots
-        max_chunk = max(
-            [c for _, c in batch.prefill_chunks] + [1 if batch.decode_reqs else 0]
-        )
-        C = self._bucket(max_chunk)
+        chunks = [c for _, c in batch.prefill_chunks]
+        C = self._bucket(max(chunks + [1 if batch.decode_reqs else 0]))
+        P = self._split_rows(C, len(chunks))
         if self.trace.on:
             n_decode = len(batch.decode_reqs)
-            self.trace.count(
-                sum(c for _, c in batch.prefill_chunks) + n_decode, B * C,
-                len(batch.prefill_chunks) + n_decode, C)
-        tokens = np.zeros((B, C), np.int32)
+            self.trace.count(sum(chunks) + n_decode,
+                             B + P * C if P else B * C,
+                             len(chunks) + n_decode, C, P)
+        tokens = np.zeros((B, 1 if P else C), np.int32)
         chunk_lens = np.zeros((B,), np.int32)
         use_last = np.zeros((B,), np.bool_)
         sample_mask = np.zeros((B,), np.bool_)
@@ -835,15 +882,22 @@ class JAXEngine:
                 use_last[slot] = True
             sample_mask[slot] = True
             sampled.append((req, slot))
-        for req, c in batch.prefill_chunks:
+        pre = ()
+        if P:
+            pre = (np.zeros((P, C), np.int32), np.full((P,), B, np.int32))
+        for i, (req, c) in enumerate(batch.prefill_chunks):
             slot = self.slot_of[req.req_id]
             chunk = req.prompt_tokens[req.prefill_done : req.prefill_done + c]
-            tokens[slot, : len(chunk)] = chunk
+            if P:
+                pre[0][i, : len(chunk)] = chunk
+                pre[1][i] = slot
+            else:
+                tokens[slot, : len(chunk)] = chunk
             chunk_lens[slot] = len(chunk)
             if req.remaining_prefill - c <= 0:  # prefill completes this round
                 sample_mask[slot] = True
                 sampled.append((req, slot))
-        return tokens, chunk_lens, use_last, sample_mask, sampled
+        return tokens, chunk_lens, use_last, sample_mask, sampled, pre
 
     def dispatch(self, batch: ScheduledBatch) -> InflightRound:
         """Stage and launch one round WITHOUT waiting for it: the jitted step
@@ -853,10 +907,11 @@ class JAXEngine:
         tr = self.trace
         with tr.span("dispatch"):
             with tr.span("stage"):
-                tokens, chunk_lens, use_last, sample_mask, sampled = (
+                tokens, chunk_lens, use_last, sample_mask, sampled, pre = (
                     self._stage(batch))
             args = (self.params, self._put(tokens), self.cache, self.lens,
                     self._put(chunk_lens))
+            pre = tuple(map(self._put, pre))
             if self.cfg.paged_kv:
                 with tr.span("block_tables"):
                     self._sync_block_tables(batch)
@@ -866,7 +921,7 @@ class JAXEngine:
             self._rng, sub = jax.random.split(self._rng)
             t_dispatch = time.perf_counter()
             with tr.span("launch"):
-                out = self._step(*args, sub)
+                out = self._step(*args, sub, *pre)
                 toks, self.cache, self.lens, self.last_token = out[:4]
                 finite = out[4] if len(out) > 4 else None
                 toks.copy_to_host_async()

@@ -3,10 +3,11 @@
 Spans are ``(name, start_ns, end_ns, round_id, req_id)`` on
 ``time.perf_counter_ns``; ``req_id`` is ``NO_REQ`` for spans that belong to
 no single request.  Counters are one tuple per dispatched round,
-``(round_id, t_ns, tokens, positions, rows, C)``: tokens scheduled (prefill
-and decode), positions the step computes (``n_slots x C``), rows holding a
-request, and the chunk bucket ``C``.  Everything stays in memory in plain
-lists.
+``(round_id, t_ns, tokens, positions, rows, C, P)``: tokens scheduled
+(prefill and decode), positions the step computes (``n_slots + P x C`` in a
+split round, ``n_slots x C`` otherwise), rows holding a request, the chunk
+bucket ``C`` and the split round's prefill rows ``P`` (0: not split).
+Everything stays in memory in plain lists.
 
 Recording is off by default.  Off, a site costs the test of ``on``: nothing
 is appended and no annotation is made.  On, each host span also enters
@@ -27,7 +28,7 @@ import jax
 NO_REQ = -1
 
 Span = Tuple[str, int, int, int, int]
-Counter = Tuple[int, int, int, int, int, int]
+Counter = Tuple[int, int, int, int, int, int, int]
 
 _OFF = contextlib.nullcontext()       # the span of a recorder that is off
 
@@ -69,9 +70,10 @@ class Recorder:
         """Record a span whose ends were taken elsewhere (no annotation)."""
         self.spans.append((name, start_ns, end_ns, self.round_id, req_id))
 
-    def count(self, tokens: int, positions: int, rows: int, C: int) -> None:
+    def count(self, tokens: int, positions: int, rows: int, C: int,
+              P: int) -> None:
         self.counters.append((self.round_id, time.perf_counter_ns(), tokens,
-                              positions, rows, C))
+                              positions, rows, C, P))
 
 
 def host_bubbles_ms(spans: List[Span]) -> List[float]:

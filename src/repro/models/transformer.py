@@ -290,7 +290,8 @@ class TransformerLM:
         return constrain(logits, ("batch", "vocab")), {"k": nk, "v": nv}
 
     def chunked_step_paged(self, params, tokens, kv_pages, lens, chunk_lens,
-                           block_tables, *, use_pallas: bool = False,
+                           block_tables, pre_tokens=None, pre_slot=None, *,
+                           use_pallas: bool = False,
                            pages_per_tile: int = 1,
                            kv_layout: str = "split",
                            buffering_depth: int = 1):
@@ -307,6 +308,16 @@ class TransformerLM:
         into the last physical page (the sink, which block tables also use as
         their pad value) and are never read back (``kv_lens`` masks them).
 
+        A round is one or two groups of rows.  Alone, ``tokens (B, C)`` gives
+        every slot one row of ``C`` tokens.  With ``pre_tokens (P, C)`` and
+        ``pre_slot (P,)`` the round is split: ``tokens (B, 1)`` are the
+        decode rows, and prefill row ``i`` carries the chunk of slot
+        ``pre_slot[i]`` (``chunk_lens`` of that slot; its decode row is
+        masked); a slot id ``>= B`` marks a padding row.  The groups meet
+        only in attention: the embedding, projections, page scatter and FFN
+        run once over the ``B + P*C`` rows, and each prefill row's
+        last-position state takes its slot's row before the unembedding.
+
         ``kv_layout="fused"`` stores the pool head-interleaved
         (``kv_pages["kv"]: (L, n_phys, ps, 2*Hkv*hd)``, heads
         ``[K0,V0,K1,V1,...]``): the round's new K/V interleave into ONE
@@ -314,39 +325,91 @@ class TransformerLM:
         with one DMA.  ``buffering_depth`` gathers run ahead of the kernels'
         dots (1 = synchronous).
 
-        Attention is the paged chunked-prefill kernel (or the paged flash-
-        decode kernel when the bucket is a pure single-token round) with a
-        pure-jnp gather oracle behind the same ``use_pallas`` flag.
+        Attention is the paged chunked-prefill kernel for rows of ``C > 1``
+        tokens and the paged flash-decode kernel for single-token rows, with
+        a pure-jnp gather oracle behind the same ``use_pallas`` flag.
         """
         from repro.kernels import ops as kops
 
         cfg = self.cfg
         assert not cfg.sliding_window, "engine demo path supports linear caches"
         fused = kv_layout == "fused"
-        B, C = tokens.shape
-        pool = kv_pages["kv"] if fused else kv_pages["k"]
-        n_phys, ps = pool.shape[1], pool.shape[2]
-        positions = lens[:, None] + jnp.arange(C)[None, :]
-        write_mask = jnp.arange(C)[None, :] < chunk_lens[:, None]
-        bidx = jnp.arange(B)
-        # logical position -> physical flat row via the block table
-        page_of = block_tables[bidx[:, None], positions // ps]     # (B, C)
-        flat_pos = page_of * ps + positions % ps
-        # padding positions scatter into the sink page (last physical page)
-        write_pos = jnp.where(write_mask, flat_pos, (n_phys - 1) * ps)
-        kv_lens = lens + chunk_lens
+        names = ("kv",) if fused else ("k", "v")
+        n_phys, ps = kv_pages[names[0]].shape[1:3]
+        sink = n_phys - 1
+        B = tokens.shape[0]
+        # each group: (tokens (R, C), lens (R,), chunk lens (R,), tables)
+        groups = [(tokens, lens, chunk_lens, block_tables)]
+        if pre_tokens is not None:
+            def of_rows(a, fill):
+                return a.at[pre_slot].get(mode="fill", fill_value=fill)
+            groups = [
+                (tokens, lens, chunk_lens.at[pre_slot].set(0, mode="drop"),
+                 block_tables),
+                (pre_tokens, of_rows(lens, 0), of_rows(chunk_lens, 0),
+                 of_rows(block_tables, sink)),
+            ]
 
-        x = params["embed"][tokens]
+        def geometry(toks, g_lens, g_chunk, g_tables):
+            R, C = toks.shape
+            positions = g_lens[:, None] + jnp.arange(C)[None, :]
+            write_mask = jnp.arange(C)[None, :] < g_chunk[:, None]
+            # logical position -> physical flat row via the block table
+            page_of = g_tables[jnp.arange(R)[:, None], positions // ps]
+            flat_pos = page_of * ps + positions % ps
+            # padding positions scatter into the sink page
+            write_pos = jnp.where(write_mask, flat_pos, sink * ps)
+            return params["embed"][toks], positions, write_mask, write_pos
+
+        def one_row(arrs):
+            """Arrays of leading dims (R, C) as one row of sum(R*C)."""
+            return jnp.concatenate(
+                [a.reshape((-1,) + a.shape[2:]) for a in arrs])[None]
+
+        per_group = [geometry(*g) for g in groups]
+        if pre_tokens is None:
+            x, positions, write_mask, write_pos = per_group[0]
+        else:
+            x, positions, write_mask, write_pos = map(one_row, zip(*per_group))
         x = constrain(x, ("batch", "seq", "embed"))
+        kv_lens = [g[1] + g[2] for g in groups]
+
+        if fused:
+            decode_fn = kops.paged_flash_decode_attention_fused
+            prefill_fn = kops.paged_prefill_chunk_attention_fused
+        else:
+            decode_fn = kops.paged_flash_decode_attention
+            prefill_fn = kops.paged_prefill_chunk_attention
+        knobs = dict(use_pallas=use_pallas, pages_per_tile=pages_per_tile,
+                     buffering_depth=buffering_depth)
+
+        def attend(q, pages):
+            outs, start = [], 0
+            for (toks, g_lens, _, g_tables), g_kv_lens in zip(groups, kv_lens):
+                R, C = toks.shape
+                if pre_tokens is None:
+                    qg = q
+                else:
+                    qg = q[0, start:start + R * C].reshape((R, C) + q.shape[2:])
+                    start += R * C
+                if C == 1:
+                    a = decode_fn(qg[:, 0], *pages, g_tables, g_kv_lens,
+                                  **knobs)[:, None]
+                else:
+                    a = prefill_fn(qg, *pages, g_tables, g_kv_lens, g_lens,
+                                   **knobs)
+                outs.append(a)
+            return outs[0] if pre_tokens is None else one_row(outs)
 
         def scatter(pages, new):
             with jax.named_scope("kv_write"):
                 return pages.reshape(n_phys * ps, -1).at[write_pos].set(
-                    new.reshape(B, C, -1)).reshape(pages.shape)
+                    new.reshape(write_pos.shape + (-1,))).reshape(pages.shape)
 
         def body(carry, xs):
-            h = L.rms_norm(carry, xs[0]["attn_norm"], cfg.norm_eps)
-            q, k_new, v_new = L.qkv_project(xs[0]["attn"], h, cfg, positions)
+            lp, pages = xs[0], xs[1:]       # pages: (n_phys, ps, lanes) each
+            h = L.rms_norm(carry, lp["attn_norm"], cfg.norm_eps)
+            q, k_new, v_new = L.qkv_project(lp["attn"], h, cfg, positions)
             # masked lanes land in the SHARED sink page: write zeros, never
             # lane values — idle rows carry NaN (all-masked softmax, same as
             # the dense path) and a NaN parked in shared storage would
@@ -354,63 +417,34 @@ class TransformerLM:
             k_new = jnp.where(write_mask[:, :, None, None], k_new, 0)
             v_new = jnp.where(write_mask[:, :, None, None], v_new, 0)
             if fused:
-                lp, ckv = xs                   # (n_phys, ps, 2*Hkv*hd)
-                Hkv, hd = k_new.shape[2], k_new.shape[3]
                 # interleave onto the head axis: ONE scatter writes K and V
+                Hkv, hd = k_new.shape[2], k_new.shape[3]
                 kv_new = jnp.stack([k_new, v_new], axis=3).reshape(
-                    B, C, 2 * Hkv, hd)
-                ckv = scatter(ckv, kv_new)
-                if C == 1:
-                    attn = kops.paged_flash_decode_attention_fused(
-                        q[:, 0], ckv, block_tables, kv_lens,
-                        use_pallas=use_pallas, pages_per_tile=pages_per_tile,
-                        buffering_depth=buffering_depth,
-                    )[:, None]
-                else:
-                    attn = kops.paged_prefill_chunk_attention_fused(
-                        q, ckv, block_tables, kv_lens, lens,
-                        use_pallas=use_pallas, pages_per_tile=pages_per_tile,
-                        buffering_depth=buffering_depth,
-                    )
-                new_pages = (ckv,)
+                    k_new.shape[:2] + (2 * Hkv, hd))
+                pages = (scatter(pages[0], kv_new),)
             else:
-                lp, ck, cv = xs                # (n_phys, ps, Hkv*hd)
-                ck = scatter(ck, k_new)
-                cv = scatter(cv, v_new)
-                if C == 1:
-                    attn = kops.paged_flash_decode_attention(
-                        q[:, 0], ck, cv, block_tables, kv_lens,
-                        use_pallas=use_pallas, pages_per_tile=pages_per_tile,
-                        buffering_depth=buffering_depth,
-                    )[:, None]
-                else:
-                    attn = kops.paged_prefill_chunk_attention(
-                        q, ck, cv, block_tables, kv_lens, lens,
-                        use_pallas=use_pallas, pages_per_tile=pages_per_tile,
-                        buffering_depth=buffering_depth,
-                    )
-                new_pages = (ck, cv)
-            y = carry + L.attn_output(lp["attn"], attn, cfg)
+                pages = (scatter(pages[0], k_new), scatter(pages[1], v_new))
+            y = carry + L.attn_output(lp["attn"], attend(q, pages), cfg)
             y = _block_ffn(lp, y, cfg)
-            return y, new_pages
+            return y, pages
 
         # layer_scan: the loop's own ops (each layer's weights and pages
         # sliced in, its updated pages stacked out, norms and residuals);
         # the scopes inside body name the rest
         with jax.named_scope("layer_scan"):
-            if fused:
-                x, (nkv,) = jax.lax.scan(
-                    body, x, (params["layers"], kv_pages["kv"])
-                )
-                new_cache = {"kv": nkv}
-            else:
-                x, (nk, nv) = jax.lax.scan(
-                    body, x, (params["layers"], kv_pages["k"], kv_pages["v"])
-                )
-                new_cache = {"k": nk, "v": nv}
+            x, new_pages = jax.lax.scan(
+                body, x,
+                (params["layers"],) + tuple(kv_pages[n] for n in names))
+        new_cache = dict(zip(names, new_pages))
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = jnp.maximum(chunk_lens - 1, 0)
-        x_last = x[bidx, last]                       # (B, D)
+        if pre_tokens is None:
+            last = jnp.maximum(chunk_lens - 1, 0)
+            x_last = x[jnp.arange(B), last]          # (B, D)
+        else:
+            P, C = pre_tokens.shape
+            pre_last = jnp.maximum(groups[1][2] - 1, 0)
+            x_pre = x[0, B:].reshape(P, C, -1)[jnp.arange(P), pre_last]
+            x_last = x[0, :B].at[pre_slot].set(x_pre, mode="drop")
         logits = self._unembed(params, x_last)
         return constrain(logits, ("batch", "vocab")), new_cache
 

@@ -8,6 +8,7 @@ import pytest
 from repro.configs import tiny_config
 from repro.core.request import Request, RequestState
 from repro.core.scheduler import ChunkedPrefillScheduler, SchedulerConfig
+from repro.engine import engine as engine_mod
 from repro.engine.engine import EngineConfig, JAXEngine, serve
 from repro.engine.kv_cache import KVBlockPool, KVPoolConfig
 from repro.engine.sampler import SamplerConfig, sample_tokens
@@ -190,18 +191,21 @@ def test_paged_and_dense_greedy_outputs_identical_with_preemption():
                          ids=["split-d1", "split-d2", "fused-d1", "fused-d2"])
 def test_warmup_covers_every_configured_shape(kv_layout, depth):
     """After ``warmup(include_swap=True)`` a pressured serve — every chunk
-    bucket, forced swap-outs and restores — must add ZERO new entries to the
-    engine step's jit cache or the swap kernels', for every configured
-    ``(kv_layout, buffering_depth)``: no serving round ever eats a cold XLA
-    compile."""
+    bucket, split rounds of one and two prefill rows, forced swap-outs and
+    restores — must add ZERO new entries to the engine step's jit cache or
+    the swap kernels', for every configured ``(kv_layout,
+    buffering_depth)``: no serving round ever eats a cold XLA compile."""
     from repro.kernels.swap import swap_gather_pages, swap_scatter_pages
 
     cfg = tiny_config("qwen1.5-0.5b")
-    eng = JAXEngine(cfg, EngineConfig(n_slots=6, max_context=128,
-                                      paged_kv=True, pipelined=True,
-                                      kv_layout=kv_layout,
-                                      buffering_depth=depth,
-                                      preemption_mode="swap", seed=3))
+    # split every mixed round the split shapes hold (P = 1, 2, 4 of 6 slots)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(engine_mod.SPLIT_ROW_COST, "oracle", 0)
+        eng = JAXEngine(cfg, EngineConfig(n_slots=6, max_context=128,
+                                          paged_kv=True, pipelined=True,
+                                          kv_layout=kv_layout,
+                                          buffering_depth=depth,
+                                          preemption_mode="swap", seed=3))
     pool = KVBlockPool(KVPoolConfig(n_blocks=11, block_size=16,
                                     bytes_per_token=4,
                                     enable_prefix_cache=True))
@@ -219,7 +223,11 @@ def test_warmup_covers_every_configured_shape(kv_layout, depth):
     res = serve(reqs, sched, eng, kv_pool=pool)
     assert res.report.n_finished == len(reqs)
     assert sched.stats.swap_preemptions > 0        # pressure actually bit
-    assert eng._step._cache_size() == n_step
+    # split rounds of one and two prefill rows ran: (C, P) per round
+    shapes = {(c[5], c[6]) for c in eng.trace.counters}
+    assert {0, 1, 2} <= {P for _, P in shapes}
+    assert shapes <= set(eng.round_shapes())
+    assert eng._step._cache_size() == n_step == len(eng.round_shapes())
     assert swap_gather_pages._cache_size() == n_gather
     assert swap_scatter_pages._cache_size() == n_scatter
 

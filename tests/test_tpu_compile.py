@@ -5,17 +5,25 @@ these tests catch what interpret mode cannot — Mosaic's tiling and lane
 alignment rules, VMEM limits — with no chip.  Shapes are Qwen1.5-0.5B's
 serving widths (16 query and 16 kv heads of head_dim 64, 16-token pages,
 a 2,049-page pool, 16 batch rows, 24 layers for the swap kernels) in the
-flat page layout the engine stores.  Each test asserts the kernel was
-compiled by Mosaic (``tpu_custom_call``), not interpreted.
+flat page layout the engine stores.  Each kernel test asserts the kernel
+was compiled by Mosaic (``tpu_custom_call``), not interpreted; the engine
+round test compiles a 2-layer paged step and reads the shapes of its
+float32 attention scores.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
 
 from repro.kernels.paged_decode_attention import (
     paged_decode_attention,
@@ -31,6 +39,7 @@ from repro.kernels.swap import (
     swap_scatter_pages,
     swap_scatter_pages_q8,
 )
+from repro.models.model import build_model
 
 B, HQ, HKV, HD, PS, N_PAGES, MAX_PAGES, LAYERS = 16, 16, 16, 64, 16, 2049, 33, 24
 LANES = HKV * HD
@@ -112,3 +121,66 @@ _SWAP_CASES = {
 def test_swap_kernels_compile_for_v5e(one_chip, case):
     fn, shapes = _SWAP_CASES[case]
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+def _engine_step_text(sharding, P: int, use_pallas: bool = False):
+    """The engine's paged round at Qwen1.5-0.5B's widths (2 layers) with
+    256-token chunks: split into one decode row per slot and ``P`` prefill
+    rows, or (``P = 0``) padded to one 256-token row per slot."""
+    cfg = dataclasses.replace(get_config("qwen1.5-0.5b"), n_layers=2)
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = ((cfg.n_layers, N_PAGES, PS, LANES), _BF16)
+    head = [(((B, 1) if P else (B, 256)), _I32), pool, pool,
+            ((B,), _I32), ((B,), _I32), ((B, MAX_PAGES), _I32)]
+    pre = [((P, 256), _I32), ((P,), _I32)] if P else []
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in head + pre]
+
+    def step(params, tokens, k, v, lens, chunk_lens, tables, *pre):
+        return model.impl.chunked_step_paged(
+            params, tokens, {"k": k, "v": v}, lens, chunk_lens, tables, *pre,
+            use_pallas=use_pallas)
+
+    return jax.jit(step).lower(params, *args).compile().as_text()
+
+
+def _query_key_blocks(text, C, keys):
+    """Element counts of the f32 tensors holding C queries against every key
+    of a block table."""
+    out = []
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        if C in shape and keys in shape:
+            out.append(math.prod(shape))
+    return out
+
+
+@pytest.mark.parametrize("split", [True, False], ids=["split", "padded"])
+def test_mixed_round_scores_only_its_prefill_rows(one_chip, split):
+    """The split round's float32 scores cover its one prefill row's 256
+    queries against the table's keys; none covers every slot's 256 queries,
+    as the padded round's do."""
+    keys = MAX_PAGES * PS
+    blocks = _query_key_blocks(_engine_step_text(one_chip, int(split)), 256,
+                               keys)
+    one_row = HQ * 256 * keys
+    if split:
+        assert blocks and max(blocks) == one_row
+    else:
+        assert max(blocks) >= B * one_row
+
+
+@pytest.mark.parametrize("P", [1, 2, 4])
+def test_split_round_compiles_pallas_kernels(one_chip, monkeypatch, P):
+    """On the Pallas path a split round runs the decode kernel over every
+    slot and the prefill kernel over its ``P`` rows in one program: both
+    compile through Mosaic."""
+    import repro.kernels
+
+    # the kernels compile for the described chip, not for this host
+    monkeypatch.setattr(repro.kernels, "interpret_mode", lambda *a: False)
+    text = _engine_step_text(one_chip, P, use_pallas=True)
+    assert text.count("tpu_custom_call") >= 2

@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs import tiny_config
 from repro.core.scheduler import ChunkedPrefillScheduler, SchedulerConfig
+from repro.engine import engine as engine_mod
 from repro.engine.engine import EngineConfig, JAXEngine, ReplicaServer
 from repro.engine.trace import NO_REQ, Recorder, host_bubbles_ms
 from repro.engine.workload import WorkloadSpec, attach_prompt_tokens, sharegpt_like
@@ -27,8 +28,12 @@ HOST_SPANS = ("round", "schedule", "drain.wait", "drain.deliver", "dispatch",
 
 @pytest.fixture(scope="module")
 def engine():
-    eng = JAXEngine(tiny_config("qwen1.5-0.5b"), EngineConfig(
-        n_slots=4, max_context=128, chunk_buckets=(1, 16, 32)))
+    # split every mixed round (P = 1, 2 of 4 slots), so rounds of both
+    # kinds are counted
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(engine_mod.SPLIT_ROW_COST, "oracle", 0)
+        eng = JAXEngine(tiny_config("qwen1.5-0.5b"), EngineConfig(
+            n_slots=4, max_context=128, chunk_buckets=(1, 16, 32)))
     eng.warmup()
     return eng
 
@@ -157,13 +162,22 @@ def test_each_request_queued_once_until_its_first_round(recorded):
 
 
 def test_counters_match_the_benchmark_count(recorded):
+    """Tokens and rows are the benchmark's own count; positions are what the
+    step computes: one decode row per slot plus ``P x C`` in a split round,
+    where the benchmark still counts ``n_slots x C``."""
     rec, probe, _, server = recorded
+    n_slots = server.engine.cfg.n_slots
     assert len(rec.counters) == len(probe.rounds) == server.rounds
     assert [c[0] for c in rec.counters] == list(range(server.rounds))
-    for (_, _, tok, pos, rows, C), (_, p_tok, p_pos, _, p_rows) in zip(
+    split = 0
+    for (_, _, tok, pos, rows, C, P), (_, p_tok, p_pos, _, p_rows) in zip(
             rec.counters, probe.rounds):
-        assert (tok, pos, rows) == (p_tok, p_pos, p_rows)
-        assert pos == server.engine.cfg.n_slots * C
+        assert (tok, rows) == (p_tok, p_rows)
+        assert p_pos == n_slots * C
+        assert (C, P) in server.engine.round_shapes()
+        assert pos == (n_slots + P * C if P else n_slots * C)
+        split += P > 0
+    assert split > 0
 
 
 def test_host_bubbles_from_spans():
@@ -208,5 +222,13 @@ def test_spans_reach_the_profiler_on_the_trace_clock(engine, tmp_path):
 @pytest.mark.parametrize("C", [1, 16])
 def test_compiled_step_names_every_scope(engine, C):
     names = re.findall(r'op_name="([^"]*)"', engine.step_hlo(C))
+    parts = {p for n in names for p in re.split(r"[/;:]", n)}
+    assert set(SCOPES) <= parts
+
+
+def test_compiled_split_step_names_every_scope(engine):
+    """A split round (one decode row per slot and one prefill row) names
+    every scope too."""
+    names = re.findall(r'op_name="([^"]*)"', engine.step_hlo(16, 1))
     parts = {p for n in names for p in re.split(r"[/;:]", n)}
     assert set(SCOPES) <= parts
