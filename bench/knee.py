@@ -1,18 +1,21 @@
 #!/usr/bin/env python3
 """Knee sweep of one cell: its configuration and mix at several arrival
-rates and seeds, one process, a fresh replica for each run, each warmed by
-the mix's own ``warm_s`` of traffic as the cell is.
+rates and seeds, one process, a fresh replica (or fleet) for each run, each
+warmed by the mix's own ``warm_s`` of traffic as the cell is.
 
     python3 bench/knee.py --workload <cell> --rates 0.3,0.4 --seeds 1,2,3 --seconds 60
 
 For each rate and seed it prints one JSON line: the backlog (requests due
-and still without a first token) at the window's start and end and averaged
-over its two halves, requests finished per second, TTFT p50/p90, ITL p95 and
-output tokens/s.  Then one line per rate with the backlog's growth (second
-half's mean less the first's) averaged over the seeds, and a last line with
-the knee: the highest rate whose mean growth is at most one request, below
-which every swept rate also holds.  The cells store their rates, as
-absolute requests/s, in their traffic files.
+and still without a first token, or, handed off to a decode replica, still
+without their second) at the window's start and end and averaged over its
+two halves, requests finished per second, TTFT p50/p90, ITL p95 and output
+tokens/s.  Then one line per rate with the backlog's growth (second half's
+mean less the first's) averaged over the seeds, and a last line with the
+knee: the highest rate whose mean growth is at most one request, below
+which every swept rate also holds.  Rates run in ascending order, and the
+sweep ends at the first rate that fails, since no higher one can be the
+knee.  The cells store their rates, as absolute requests/s, in their
+traffic files.
 """
 from __future__ import annotations
 
@@ -29,14 +32,24 @@ from bench import cell as cellmod, generator, modelcfg, stats  # noqa: E402
 from bench.run import load_cell, place_compile_cache, require_chips  # noqa: E402
 
 
+def waiting(r, t: float) -> bool:
+    """Due by ``t`` and still waiting for its first token, or, handed off
+    by a fleet's prefill replica, for the decode replica's first."""
+    if r.arrival_time > t:
+        return False
+    if r.first_token_time is None or r.first_token_time > t:
+        return True
+    return bool(r.handoffs) and r.max_new_tokens > 1 and (
+        len(r.token_times) < 2 or r.token_times[1] > t)
+
+
 def backlog(reqs, t: float) -> int:
-    return sum(r.arrival_time <= t and (r.first_token_time is None
-                                        or r.first_token_time > t)
-               for r in reqs)
+    return sum(waiting(r, t) for r in reqs)
 
 
-def sweep_one(cfg, d, mc, mix, rate: float, seconds: float, seed: int) -> dict:
-    system = cellmod.build(cfg, d, mc, seed)
+def sweep_one(cfg, d, mc, mix, rate: float, seconds: float, seed: int,
+              replicas=None, devices=None) -> dict:
+    system = cellmod.build(cfg, d, mc, seed, replicas, devices)
     probe = cellmod.Probe()
     cellmod.instrument(system, d, probe)
     mix = dict(mix, drain_s=0)
@@ -68,13 +81,15 @@ def main() -> None:
     args = p.parse_args()
     place_compile_cache()
     _, cellspec = load_cell(args.workload)
-    require_chips(cellspec["chips"])
+    devices = require_chips(cellspec["chips"])
+    replicas = cellmod.layout(cellspec)
     cfg = modelcfg.load(cellspec["config"])
     mix = generator.load_mix(cellspec["traffic"])
     d, mc = modelcfg.dims(cfg), modelcfg.program_config(cfg)
     growth = {}
-    for rate in (float(x) for x in args.rates.split(",")):
-        runs = [sweep_one(cfg, d, mc, mix, rate, args.seconds, int(s))
+    for rate in sorted(float(x) for x in args.rates.split(",")):
+        runs = [sweep_one(cfg, d, mc, mix, rate, args.seconds, int(s),
+                          replicas, devices)
                 for s in args.seeds.split(",")]
         for r in runs:
             print(json.dumps(r), flush=True)
@@ -82,6 +97,8 @@ def main() -> None:
         print(json.dumps({"rate_rps": rate, "mean_backlog_growth": growth[rate],
                           "finished_per_s": [r["finished_per_s"] for r in runs]}),
               flush=True)
+        if growth[rate] > 1:
+            break       # no higher rate can be the knee
     knee = None
     for rate in sorted(growth):
         if growth[rate] > 1:

@@ -1,8 +1,13 @@
-"""Device: share of the traced time during which the replica had work
-outstanding and no operation ran on the device, in percent."""
+"""Device: share of the traced time during which a replica had work
+outstanding and no operation ran on its device, in percent.  Over several
+devices (a fleet, one replica a chip) each device counts only the time its
+own replica had work, and the metric is the highest device's share."""
 
 
 def read(run):
-    if run.trace is None or run.trace["work_s"] <= 0:
+    if run.trace is None:
         return None
-    return 100.0 * run.trace["idle_work_s"] / run.trace["work_s"]
+    devices = run.trace.get("per_device") or {"all": run.trace}
+    shares = [100.0 * v["idle_work_s"] / v["work_s"]
+              for v in devices.values() if v["work_s"] > 0]
+    return max(shares) if shares else None

@@ -3,12 +3,16 @@ it that the benchmark needs: the plain dimensions its own yardstick reads
 (reference, FLOP count) and the program's ``ModelConfig``.
 
 The file keeps the source's ``config.json`` keys and values, as run, at its
-top level; ``reduced`` names the keys changed from the source.
+top level; ``reduced`` names the keys changed from the source, and
+``architecture`` the file under ``bench/arch`` that reads them (``dense``
+where the key is absent).
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
+
+from bench import arch
 
 CONFIG_DIR = Path(__file__).resolve().parent / "configs"
 
@@ -21,21 +25,10 @@ def load(name: str) -> dict:
 
 
 def dims(cfg: dict) -> dict:
-    """The shapes and constants of the published architecture."""
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {
-        "d_model": d,
-        "n_heads": h,
-        "n_kv_heads": cfg["num_key_value_heads"],
-        "head_dim": cfg.get("head_dim") or d // h,
-        "d_ff": cfg["intermediate_size"],
-        "n_layers": cfg["num_hidden_layers"],
-        "vocab_size": cfg["vocab_size"],
-        "tied": bool(cfg["tie_word_embeddings"]),
-        "qkv_bias": bool(cfg["attention_bias"]),
-        "eps": float(cfg["rms_norm_eps"]),
-        "rope_theta": float(cfg["rope_theta"]),
-    }
+    """The shapes and constants of the published architecture, as the file's
+    architecture (``bench/arch``) reads them, with its name."""
+    name = cfg.get("architecture", arch.DEFAULT)
+    return {**arch.load(name).dims(cfg), "architecture": name}
 
 
 def program_config(cfg: dict):
@@ -46,9 +39,5 @@ def program_config(cfg: dict):
 
     d = dims(cfg)
     return get_config(cfg["registry"]).with_(
-        n_layers=d["n_layers"], d_model=d["d_model"], n_heads=d["n_heads"],
-        n_kv_heads=d["n_kv_heads"], head_dim=d["head_dim"], d_ff=d["d_ff"],
-        vocab_size=d["vocab_size"], qkv_bias=d["qkv_bias"],
-        tie_embeddings=d["tied"], norm_eps=d["eps"],
-        rope_theta=d["rope_theta"], param_dtype=cfg["torch_dtype"],
-        compute_dtype=cfg["torch_dtype"])
+        param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"],
+        **arch.of(d).program_overrides(d))

@@ -1,8 +1,9 @@
-"""Plain float32 reference of the served architecture (a decoder-only
-transformer as in Hugging Face's Qwen2 and Mistral: RMSNorm, rotary
-embeddings with the half-split rotation, grouped-query causal attention,
-SwiGLU), written from the published description and importing nothing of the
-program.
+"""Plain float32 reference of the served model, written from the published
+description and importing nothing of the program: the embedding, one layer
+at a time of the configuration's architecture (``bench/arch/<name>.py``,
+built from the shared pieces here: RMSNorm, rotary embeddings with the
+half-split rotation, grouped-query causal attention, float32 matmuls), the
+final norm and the unembedding.
 
 It runs one layer at a time over every sequence, making that layer's weights
 from the seed (``bench.weights.layer``) and upcasting them to float32, so a
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import arch, weights
 
 HI = jax.lax.Precision.HIGHEST
 SEQ_BUCKET = 512       # sequences pad to a multiple of this (fewer compiles)
@@ -38,18 +39,18 @@ def _f8(x, axis):
     return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _mm(a, w, quant: bool):
+def mm(a, w, quant: bool):
     """(T, K) @ (K, N) in float32."""
     if quant:
         a, w = _f8(a, -1), _f8(w, None)
     return jnp.dot(a, w, precision=HI)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (T, H, hd); rotate the two halves of each head by position."""
     T, _, hd = x.shape
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -59,7 +60,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v):
+def attention(q, k, v):
     """Causal grouped-query attention; q (T, H, hd), k/v (T, K, hd)."""
     T, H, hd = q.shape
     g = H // k.shape[1]
@@ -81,24 +82,9 @@ def _attention(q, k, v):
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
 def _block(w, x, d_items, quant: bool):
+    """One layer of the configuration's architecture (``bench/arch``)."""
     d = dict(d_items)
-    T, D = x.shape
-    H, K, hd = d["n_heads"], d["n_kv_heads"], d["head_dim"]
-    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    a = _rms(x, w["attn_norm"], d["eps"])
-    at = w["attn"]
-    q = _mm(a, at["wq"].reshape(D, H * hd), quant).reshape(T, H, hd)
-    k = _mm(a, at["wk"].reshape(D, K * hd), quant).reshape(T, K, hd)
-    v = _mm(a, at["wv"].reshape(D, K * hd), quant).reshape(T, K, hd)
-    if "bq" in at:
-        q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-    q, k = _rope(q, d["rope_theta"]), _rope(k, d["rope_theta"])
-    o = _attention(q, k, v).reshape(T, H * hd)
-    x = x + _mm(o, at["wo"].reshape(H * hd, D), quant)
-    a = _rms(x, w["ffn_norm"], d["eps"])
-    f = w["ffn"]
-    h = jax.nn.silu(_mm(a, f["w_gate"], quant)) * _mm(a, f["w_up"], quant)
-    return x + _mm(h, f["w_down"], quant)
+    return arch.of(d).block(w, x, d, quant)
 
 
 @jax.jit
@@ -109,12 +95,12 @@ def _embed(table, tokens):
 @functools.partial(jax.jit, static_argnums=(4, 5))
 def _scores(top, rows, cands, eps_arr, tied: bool, quant: bool):
     """Per row: best logit, its token, and the logits of ``cands``."""
-    x = _rms(rows, top["final_norm"].astype(jnp.float32), eps_arr)
+    x = rms(rows, top["final_norm"].astype(jnp.float32), eps_arr)
     if tied:
         w = top["embed"].astype(jnp.float32).T
     else:
         w = top["lm_head"].astype(jnp.float32)
-    logits = _mm(x, w, quant)
+    logits = mm(x, w, quant)
     return (logits.max(-1), jnp.argmax(logits, -1).astype(jnp.int32),
             jnp.take_along_axis(logits, cands, axis=-1))
 

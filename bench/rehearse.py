@@ -4,10 +4,15 @@
 so that a configuration's engine sizes can be chosen without the chip.
 
     JAX_PLATFORMS=cpu python3 bench/rehearse.py qwen1.5-0.5b [n_slots max_context kv_blocks]
+    JAX_PLATFORMS=cpu python3 bench/rehearse.py --cell qwen05-fleet-1p3d
 
 It compiles the composition the engine jits (``chunked_step_paged``, greedy
 sampling and the length update, the page pool donated) at the file's sizes,
-or the ones given, and prints ``memory_analysis()`` per bucket.  The chip's
+or the ones given, and prints ``memory_analysis()`` per bucket.  With
+``--cell`` it takes the cell's configuration and compiles for each of its
+replicas' devices of a described 2x2 v5e host (a fleet: one replica a
+chip), so that every device's program and memory is seen before a
+four-chip call.  The chip's
 compiler refuses a program over the device's memory, so a size that
 compiles here fits; leave room for what else the process holds.
 """
@@ -24,23 +29,38 @@ sys.path[:0] = [str(Path(__file__).resolve().parent.parent),
 
 
 def main() -> None:
-    import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import modelcfg, weights
-    from repro.engine.sampler import SamplerConfig, sample_tokens
+    from bench import cell as cellmod, modelcfg
+    from bench.run import load_cell
     from repro.models.model import build_model
 
-    cfg = modelcfg.load(sys.argv[1])
+    n_replicas = 1
+    if sys.argv[1] == "--cell":
+        _, cellspec = load_cell(sys.argv[2])
+        cfg = modelcfg.load(cellspec["config"])
+        layout = cellmod.layout(cellspec)
+        n_replicas = 1 if layout is None else layout["prefill"] + layout["decode"]
+    else:
+        cfg = modelcfg.load(sys.argv[1])
     eng = cfg["engine"]
     B, S, n_blocks = ((int(x) for x in sys.argv[2:5]) if len(sys.argv) > 4 else
                       (eng["n_slots"], eng["max_context"], eng["kv_blocks"]))
     d, mc = modelcfg.dims(cfg), modelcfg.program_config(cfg)
     impl = build_model(mc).impl
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    sh = SingleDeviceSharding(topo.devices[0])
+    for device in topo.devices[:n_replicas]:
+        compile_step(cfg, d, impl, SingleDeviceSharding(device), device.id,
+                     B, S, n_blocks)
+
+
+def compile_step(cfg, d, impl, sh, device_id, B, S, n_blocks) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from bench import weights
+    from repro.engine.sampler import SamplerConfig, sample_tokens
 
     def shaped(tree):
         return jax.tree.map(
@@ -75,8 +95,8 @@ def main() -> None:
             i32(B, max_pages), i32(B), b(B), b(B),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sh),
         ).compile().memory_analysis()
-        print(f"{cfg['name']} slots={B} max_context={S} kv_blocks={n_blocks} "
-              f"C={C}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+        print(f"{cfg['name']} device={device_id} slots={B} max_context={S} "
+              f"kv_blocks={n_blocks} C={C}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
               f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, "
               f"aliased {m.alias_size_in_bytes / 1e9:.2f} GB "
               f"({time.time() - t:.0f} s)", flush=True)

@@ -4,8 +4,10 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration (``bench/configs``) and a
-traffic mix (``bench/traffic``).  The run makes weights and requests from
-the seed, builds and warms the program's replica loop (set-up), offers the
+traffic mix (``bench/traffic``); a cell on several chips lays out one replica
+a chip behind the program's router (``bench/cells/<cell>.json``).  The run
+makes weights and requests from the seed, builds and warms the program's
+replica loop or fleet (set-up), offers the
 mix's open-loop load for a warm period and then a window of ``--seconds``,
 and checks the served tokens against the float32 reference.  ``--trace 0``
 reports the cell's end-to-end metrics, ``--trace 1`` its per-layer metrics
@@ -72,6 +74,10 @@ def place_compile_cache() -> None:
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # every program of the cell stays: a fleet compiles its step programs
+    # once per device (the TPU cache key holds the device assignment), more
+    # than a size cap set for one replica may hold
+    jax.config.update("jax_compilation_cache_max_size", -1)
 
 
 def require_chips(n: int):
@@ -109,6 +115,36 @@ def reader(name: str):
     raise SystemExit(f"bench: no reader for metric {name!r} in bench/metrics")
 
 
+def engine_info(engine) -> dict:
+    c = engine.cfg
+    return {"n_slots": c.n_slots, "max_context": c.max_context,
+            "kv_blocks": engine.kv_pool.cfg.n_blocks, "pipelined": c.pipelined,
+            "use_pallas": c.use_pallas, "kv_layout": c.kv_layout}
+
+
+def fleet_info(system, reqs, probe, window_ns, peaks: dict) -> dict:
+    """Per replica its device, engine settings, memory peak and rounds (in
+    all, and dispatched in the window), and the router's handoff counts."""
+    w0, w1 = window_ns
+    st = system.router.store.stats
+    return {
+        "replicas": [{"name": s.name, "device": s.engine.device.id,
+                      "engine": engine_info(s.engine),
+                      "memory_peak_bytes": peaks[str(s.engine.device.id)],
+                      "rounds": s.rounds,
+                      "rounds_in_window": sum(
+                          name == s.name and w0 <= r[0] < w1
+                          for r, name in zip(probe.rounds, probe.round_replica))}
+                     for s in system.servers],
+        "handoffs": {"delivered": st.delivered, "dropped": st.dropped,
+                     "colocated": st.colocated, "prefetched": st.prefetched,
+                     "bytes_moved": st.bytes_moved},
+        "requests_handed_off": sum(r.handoffs > 0 for r in reqs),
+        "requests_with_first_token": sum(r.first_token_time is not None
+                                         for r in reqs),
+    }
+
+
 def run(args, *, control: bool = False, cell=None, devices=None,
         peak=None) -> dict:
     """One run of the cell; returns the result object (and, with
@@ -122,6 +158,7 @@ def run(args, *, control: bool = False, cell=None, devices=None,
     from bench import cell as cellmod, check, generator, modelcfg, stats, xplane
 
     spec, cellspec = cell or load_cell(args.workload)
+    replicas = cellmod.layout(cellspec)
     if devices is None:
         devices = require_chips(cellspec["chips"])
     dev = devices[0]
@@ -136,7 +173,7 @@ def run(args, *, control: bool = False, cell=None, devices=None,
                          "configuration's max_context")
     wanted = metrics_for(spec, args.workload, args.trace)
 
-    system = cellmod.build(cfg, d, mc, args.seed)
+    system = cellmod.build(cfg, d, mc, args.seed, replicas, devices)
     probe = cellmod.Probe()
     cellmod.instrument(system, d, probe)
     arrivals = generator.arrivals(mix, args.seconds, args.seed, d["vocab_size"])
@@ -158,7 +195,7 @@ def run(args, *, control: bool = False, cell=None, devices=None,
 
     def stop_trace():
         tracing.t1_ns = time.perf_counter_ns()
-        jax.block_until_ready(system.engine.last_token)
+        jax.block_until_ready([s.engine.last_token for s in system.servers])
         jax.profiler.stop_trace()
         tracing.on = False
         try:
@@ -190,8 +227,11 @@ def run(args, *, control: bool = False, cell=None, devices=None,
     for a, r in zip(arrivals, run_.requests):
         prompts[r.req_id] = a.prompt
 
-    mem = dev.memory_stats() or {}
-    memory_peak = int(mem.get("peak_bytes_in_use", 0))
+    # the fullest of the cell's devices
+    peaks = {str(s.engine.device.id): int(
+        (s.engine.device.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for s in system.servers}
+    memory_peak = max(peaks.values())
 
     reqs = run_.requests
     failed = sum(r.shed_reason is not None for r in reqs)
@@ -227,13 +267,15 @@ def run(args, *, control: bool = False, cell=None, devices=None,
         "window_s": list(run_.window),
         "setup_s": setup_s,
     }
-    engine_cfg = system.engine.cfg
-    info["engine"] = {"n_slots": engine_cfg.n_slots,
-                      "max_context": engine_cfg.max_context,
-                      "kv_blocks": cfg["engine"]["kv_blocks"],
-                      "pipelined": engine_cfg.pipelined,
-                      "use_pallas": engine_cfg.use_pallas,
-                      "kv_layout": engine_cfg.kv_layout}
+    info["engine"] = engine_info(system.engine)
+    fleet = system.router is not None
+    if fleet:
+        info["fleet"] = fleet_info(system, reqs, probe, (w0_ns, w1_ns), peaks)
+    # the trace's plane of each replica's device
+    planes = {s.name: f"/device:TPU:{s.engine.device.id}" for s in system.servers}
+    # each request's first two token stamps, for the handoff's gap
+    stamps = [SimpleNamespace(handoffs=r.handoffs, token_ns=[
+        int((run_.t0 + t) * 1e9) for t in r.token_times[:2]]) for r in reqs]
     del system, run_.requests
     gc.collect()
 
@@ -243,13 +285,17 @@ def run(args, *, control: bool = False, cell=None, devices=None,
         window = (tracing.t0_ns, tracing.t1_ns)
         spans = [s for s in probe.spans
                  if s[2] > window[0] and s[1] < window[1]]
-        trace = xplane.reduce(tr, window, probe.outstanding, spans,
-                              tracing.anchor)
+        outstanding = probe.outstanding
+        if fleet:
+            # each device's own replica's work
+            outstanding = {p: probe.busy.get(name, []) for name, p in planes.items()}
+        trace = xplane.reduce(tr, window, outstanding, spans, tracing.anchor)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
     view = SimpleNamespace(
         probe=probe, window_ns=(w0_ns, w1_ns), trace=trace,
-        trace_window_ns=(tracing.t0_ns, tracing.t1_ns), peak=peak, dims=d)
+        trace_window_ns=(tracing.t0_ns, tracing.t1_ns), peak=peak, dims=d,
+        requests=stamps)
     metrics = {}
     for m in wanted:
         if args.trace:
@@ -288,6 +334,8 @@ def run(args, *, control: bool = False, cell=None, devices=None,
         result["breakdown"] = {"device_ops": [list(x) for x in trace["device_ops"]],
                                "idle_gaps": [list(x) for x in trace["idle_gaps"]]}
         info["trace"] = {k: trace[k] for k in ("work_s", "idle_work_s")}
+        if fleet:
+            info["trace"]["per_device"] = trace["per_device"]
     result["checks"] = checks
     if control:
         result["control"] = {"correct": control_correct, "gap": control_gap}

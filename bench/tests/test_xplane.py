@@ -79,3 +79,23 @@ def test_recorded_trace_by_hand():
                   if n.startswith("%fusion.164 ") and e > a and s < b) / 1e9
     assert ops[top] == pytest.approx(by_hand) and by_hand == pytest.approx(0.5741, abs=1e-3)
     assert sum(ops.values()) <= out["busy_s"]
+
+
+def test_each_device_against_its_own_work():
+    """A fleet: each device's idle time counts only while its own replica
+    had work, and the lists take each device's largest in turn."""
+    trace = xplane.DeviceTrace({
+        "/device:TPU:0": [("%a = f32[1]{0} fusion()", 0, 100), ("%b = f32[1]{0} fusion()", 100, 150)],
+        "/device:TPU:1": [("%a = f32[1]{0} fusion()", 0, 20)],
+    }, anchor_ns=0)
+    out = xplane.reduce(trace, (0, 200), {"/device:TPU:0": [(0, 200)],
+                                          "/device:TPU:1": [(0, 40)]},
+                        [("decode0/drain", 20, 40)], 0)
+    assert out["per_device"]["/device:TPU:0"] == pytest.approx(
+        {"busy_s": 150e-9, "work_s": 200e-9, "idle_work_s": 50e-9})
+    assert out["per_device"]["/device:TPU:1"] == pytest.approx(
+        {"busy_s": 20e-9, "work_s": 40e-9, "idle_work_s": 20e-9})
+    assert out["busy_s"] == pytest.approx(85e-9)
+    assert [n for n, _ in out["device_ops"]] == ["TPU:0 a f32[1]", "TPU:1 a f32[1]",
+                                                 "TPU:0 b f32[1]"]
+    assert [n for n, _ in out["idle_gaps"]] == ["TPU:0 host", "TPU:1 decode0/drain"]

@@ -1,5 +1,7 @@
 """Random weights from ``--seed``, made on the device in the type they are
-served in (bfloat16), in the layout the program's ``TransformerLM`` takes.
+served in (bfloat16), in the layout the program's ``TransformerLM`` takes:
+the embedding and final norm here, each layer's leaves as the configuration's
+architecture lists them (``bench/arch``).
 
 Each leaf draws from its own key, and each layer of a stacked leaf from
 ``fold_in(leaf key, layer)``, so ``layer(seed, l)`` gives exactly the slice
@@ -16,6 +18,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from bench import arch
+
 DTYPE = jnp.bfloat16
 
 
@@ -23,41 +27,21 @@ def _key(seed: int):
     return jax.random.PRNGKey(seed % (2**32 - 1))
 
 
-def _layer_specs(d: dict):
-    """(group, name, shape, std, mean) of one layer's leaves."""
-    D, H, K, hd, F = (d["d_model"], d["n_heads"], d["n_kv_heads"],
-                      d["head_dim"], d["d_ff"])
-    specs = [
-        (None, "attn_norm", (D,), 0.1, 1.0),
-        ("attn", "wq", (D, H, hd), 1 / math.sqrt(D), 0.0),
-        ("attn", "wk", (D, K, hd), 1 / math.sqrt(D), 0.0),
-        ("attn", "wv", (D, K, hd), 1 / math.sqrt(D), 0.0),
-        ("attn", "wo", (H, hd, D), 1 / math.sqrt(H * hd), 0.0),
-        (None, "ffn_norm", (D,), 0.1, 1.0),
-        ("ffn", "w_gate", (D, F), 1 / math.sqrt(D), 0.0),
-        ("ffn", "w_up", (D, F), 1 / math.sqrt(D), 0.0),
-        ("ffn", "w_down", (F, D), 1 / math.sqrt(F), 0.0),
-    ]
-    if d["qkv_bias"]:
-        specs += [("attn", "bq", (H, hd), 0.2, 0.0),
-                  ("attn", "bk", (K, hd), 0.2, 0.0),
-                  ("attn", "bv", (K, hd), 0.2, 0.0)]
-    return specs
-
-
 def _normal(key, shape, std, mean):
     return (jax.random.normal(key, shape, jnp.float32) * std + mean).astype(DTYPE)
 
 
 def _layer(d: dict, key, l):
-    out: dict = {"attn": {}, "ffn": {}}
-    for i, (group, name, shape, std, mean) in enumerate(_layer_specs(d)):
+    """Layer ``l``'s leaves as the architecture lists them
+    (``bench/arch/<name>.py:layer_specs``)."""
+    out: dict = {}
+    for i, (group, name, shape, std, mean) in enumerate(arch.of(d).layer_specs(d)):
         k = jax.random.fold_in(jax.random.fold_in(key, 100 + i), l)
         leaf = _normal(k, shape, std, mean)
         if group is None:
             out[name] = leaf
         else:
-            out[group][name] = leaf
+            out.setdefault(group, {})[name] = leaf
     return out
 
 

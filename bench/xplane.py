@@ -1,6 +1,6 @@
 """Reduction of a JAX profiler trace (``.xplane.pb``) to the numbers the
 benchmark reports: device busy time (the union of the intervals in which an
-operation ran), the device's idle time while the replica had work
+operation ran), each device's idle time while its replica had work
 outstanding, the operations that took most time, and the longest idle gaps,
 each named by what the host was doing in it.
 
@@ -13,7 +13,7 @@ from __future__ import annotations
 from collections import defaultdict
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple, Union
 
 ANCHOR = "bench.anchor"
 OPS_LINE = "XLA Ops"
@@ -90,24 +90,39 @@ def gaps(merged: Sequence[Interval], a: int, b: int) -> List[Interval]:
     return out
 
 
-def reduce(trace: DeviceTrace, window: Interval, outstanding: Sequence[Interval],
+def reduce(trace: DeviceTrace, window: Interval,
+           outstanding: Union[Sequence[Interval], Dict[str, Sequence[Interval]]],
            host_spans: Sequence[Tuple[str, int, int]], anchor_host_ns: int) -> dict:
     """Numbers of the traced ``window`` (host clock, ns): busy seconds
     averaged over the devices, idle seconds while work was outstanding, the
-    top device operations and the longest idle gaps by host span."""
+    top device operations and the longest idle gaps by host span, and each
+    device's own busy, work and idle seconds (``per_device``).
+
+    ``outstanding`` holds the intervals in which work was outstanding, for
+    every device alike, or per device plane (a fleet: each replica's own).
+    Over several devices an op or a gap is labelled with its device, and the
+    lists take each device's largest in turn, so that every device shows."""
     shift = trace.anchor_ns - anchor_host_ns        # host ns -> trace ns
     a, b = window[0] + shift, window[1] + shift
-    work = union([(x + shift, y + shift) for x, y in outstanding])
     spans = sorted((s + shift, e + shift, n) for n, s, e in host_spans)
+    many = len(trace.ops) > 1
 
-    busy_s, idle_work_s, work_s = 0.0, 0.0, 0.0
-    by_op: Dict[str, float] = defaultdict(float)
-    idle: List[Tuple[str, float]] = []
-    for evs in trace.ops.values():
+    def shifted(intervals):
+        return union([(x + shift, y + shift) for x, y in intervals])
+
+    common = None if isinstance(outstanding, dict) else shifted(outstanding)
+    per_device: Dict[str, dict] = {}
+    ops_of: List[List[Tuple[str, float]]] = []
+    gaps_of: List[List[Tuple[str, float]]] = []
+    for plane, evs in trace.ops.items():
+        tag = f"{plane.rsplit('device:', 1)[-1]} " if many else ""
+        work = common if common is not None else shifted(outstanding.get(plane, []))
         busy = union([(s, e) for _, s, e in evs])
-        busy_s += overlap(busy, a, b) / 1e9
+        by_op: Dict[str, float] = defaultdict(float)
         for name, s, e in leaves(evs):
-            by_op[label(name)] += max(0, min(e, b) - max(s, a)) / 1e9
+            by_op[tag + label(name)] += max(0, min(e, b) - max(s, a)) / 1e9
+        idle: List[Tuple[str, float]] = []
+        work_s = idle_work_s = 0.0
         for x, y in work:
             x, y = max(x, a), min(y, b)
             if x >= y:
@@ -115,16 +130,34 @@ def reduce(trace: DeviceTrace, window: Interval, outstanding: Sequence[Interval]
             work_s += (y - x) / 1e9
             for g0, g1 in gaps(busy, x, y):
                 idle_work_s += (g1 - g0) / 1e9
-                idle.append((_label(spans, g0, g1), (g1 - g0) / 1e9))
+                idle.append((tag + _label(spans, g0, g1), (g1 - g0) / 1e9))
+        per_device[plane] = {"busy_s": overlap(busy, a, b) / 1e9,
+                             "work_s": work_s, "idle_work_s": idle_work_s}
+        ops_of.append(sorted(by_op.items(), key=lambda kv: -kv[1]))
+        gaps_of.append(sorted(idle, key=lambda kv: -kv[1]))
     n = len(trace.ops)
     return {
-        "busy_s": busy_s / n,
+        "busy_s": sum(v["busy_s"] for v in per_device.values()) / n,
         "window_s": (b - a) / 1e9,
-        "work_s": work_s / n,
-        "idle_work_s": idle_work_s / n,
-        "device_ops": sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP],
-        "idle_gaps": sorted(idle, key=lambda kv: -kv[1])[:TOP],
+        "work_s": sum(v["work_s"] for v in per_device.values()) / n,
+        "idle_work_s": sum(v["idle_work_s"] for v in per_device.values()) / n,
+        "device_ops": _take_turns(ops_of),
+        "idle_gaps": _take_turns(gaps_of),
+        "per_device": per_device,
     }
+
+
+def _take_turns(lists: Sequence[Sequence[Tuple[str, float]]]) -> list:
+    """The first ``TOP`` entries of each device's list (largest first),
+    taken one device at a time, largest first in each turn."""
+    out: list = []
+    for k in range(TOP):
+        turn = sorted((lst[k] for lst in lists if k < len(lst)),
+                      key=lambda kv: -kv[1])
+        out.extend(turn[:TOP - len(out)])
+        if len(out) >= TOP:
+            break
+    return out
 
 
 def leaves(evs: Sequence[Tuple[str, int, int]]) -> List[Tuple[str, int, int]]:
