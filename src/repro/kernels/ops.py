@@ -1,9 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` selects kernel vs pure-jnp oracle; on CPU the kernels run in
+``use_pallas`` selects kernel vs pure-jnp path; on CPU the kernels run in
 interpret mode (Python-executed kernel bodies — correctness, not speed); on
 TPU the same calls compile to Mosaic; any other platform is refused
 (``repro.kernels.interpret_mode``).  The engine flips this with one flag.
+The pure-jnp path is the oracle (``ref``) everywhere but in paged prefill,
+which serves ``paged_prefill_tiled``: the oracle's temporaries grow with the
+whole block table.
 
 Paged pools arrive as ``(n_pages, page_size, H, hd)`` or flattened
 ``(n_pages, page_size, H*hd)`` (the engine's stored layout); the oracles see
@@ -26,6 +29,10 @@ from repro.kernels.paged_decode_attention import (
 from repro.kernels.paged_prefill_attention import (
     paged_prefill_attention,
     paged_prefill_attention_fused,
+)
+from repro.kernels.paged_prefill_tiled import (
+    paged_prefill_attention_tiled,
+    paged_prefill_attention_tiled_fused,
 )
 from repro.kernels.swap import (
     swap_gather_pages, swap_gather_pages_q8, swap_scatter_pages,
@@ -81,13 +88,11 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables, kv_lens,
     """(B, Sq, Hq, hd) chunk vs a (n_pages, ps, Hkv, hd) physical page pool
     addressed through per-sequence block tables, with causal offset.
     ``pages_per_tile`` pages are DMA-gathered into one MXU K/V tile per grid
-    step (the oracle is tile-size-agnostic: indirection is data movement);
-    ``buffering_depth`` gathers run ahead of the dot (1 = synchronous)."""
+    step of the kernel; ``buffering_depth`` gathers run ahead of the dot (1 =
+    synchronous).  Without Pallas, ``paged_prefill_attention_tiled``."""
     if not use_pallas:
-        hd = q.shape[-1]
-        return ref.paged_prefill_attention_ref(
-            q, _heads(k_pages, hd), _heads(v_pages, hd), block_tables,
-            kv_lens, q_offset)
+        return paged_prefill_attention_tiled(
+            q, k_pages, v_pages, block_tables, kv_lens, q_offset)
     return paged_prefill_attention(
         q, k_pages, v_pages, block_tables, kv_lens, q_offset,
         block_q=block_q, pages_per_tile=pages_per_tile,
@@ -104,8 +109,8 @@ def paged_prefill_chunk_attention_fused(q, kv_pages, block_tables, kv_lens,
     """``paged_prefill_chunk_attention`` over a fused head-interleaved pool
     ``(n_pages, ps, 2*Hkv, hd)`` — one DMA per page feeds both K and V."""
     if not use_pallas:
-        return ref.paged_prefill_attention_fused_ref(
-            q, _heads(kv_pages, q.shape[-1]), block_tables, kv_lens, q_offset)
+        return paged_prefill_attention_tiled_fused(
+            q, kv_pages, block_tables, kv_lens, q_offset)
     return paged_prefill_attention_fused(
         q, kv_pages, block_tables, kv_lens, q_offset,
         block_q=block_q, pages_per_tile=pages_per_tile,

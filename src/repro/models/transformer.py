@@ -326,8 +326,9 @@ class TransformerLM:
         dots (1 = synchronous).
 
         Attention is the paged chunked-prefill kernel for rows of ``C > 1``
-        tokens and the paged flash-decode kernel for single-token rows, with
-        a pure-jnp gather oracle behind the same ``use_pallas`` flag.
+        tokens and the paged flash-decode kernel for single-token rows; with
+        ``use_pallas=False``, the tiled jnp prefill
+        (``kernels/paged_prefill_tiled.py``) and the decode gather oracle.
         """
         from repro.kernels import ops as kops
 

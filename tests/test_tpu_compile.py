@@ -8,7 +8,7 @@ a 2,049-page pool, 16 batch rows, 24 layers for the swap kernels) in the
 flat page layout the engine stores.  Each kernel test asserts the kernel
 was compiled by Mosaic (``tpu_custom_call``), not interpreted; the engine
 round test compiles a 2-layer paged step and reads the shapes of its
-float32 attention scores.
+float32 attention scores, one key tile of the served prefill attention.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -33,6 +33,7 @@ from repro.kernels.paged_prefill_attention import (
     paged_prefill_attention,
     paged_prefill_attention_fused,
 )
+from repro.kernels.paged_prefill_tiled import TILE_KEYS
 from repro.kernels.swap import (
     swap_gather_pages,
     swap_gather_pages_q8,
@@ -148,8 +149,8 @@ def _engine_step_text(sharding, P: int, use_pallas: bool = False):
 
 
 def _query_key_blocks(text, C, keys):
-    """Element counts of the f32 tensors holding C queries against every key
-    of a block table."""
+    """Element counts of the f32 tensors holding C queries against ``keys``
+    keys (one tile of the served prefill attention's walk)."""
     out = []
     for dims in re.findall(r"f32\[([\d,]+)\]", text):
         shape = [int(d) for d in dims.split(",")]
@@ -161,9 +162,9 @@ def _query_key_blocks(text, C, keys):
 @pytest.mark.parametrize("split", [True, False], ids=["split", "padded"])
 def test_mixed_round_scores_only_its_prefill_rows(one_chip, split):
     """The split round's float32 scores cover its one prefill row's 256
-    queries against the table's keys; none covers every slot's 256 queries,
+    queries against a tile of keys; none covers every slot's 256 queries,
     as the padded round's do."""
-    keys = MAX_PAGES * PS
+    keys = TILE_KEYS
     blocks = _query_key_blocks(_engine_step_text(one_chip, int(split)), 256,
                                keys)
     one_row = HQ * 256 * keys
@@ -184,3 +185,31 @@ def test_split_round_compiles_pallas_kernels(one_chip, monkeypatch, P):
     monkeypatch.setattr(repro.kernels, "interpret_mode", lambda *a: False)
     text = _engine_step_text(one_chip, P, use_pallas=True)
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_served_prefill_temporaries_fit_at_mistral_large_widths(one_chip):
+    """The served prefill attention at mistral-large-123b's widths and the
+    benchmark cell's sizes (8 rows of a C=256 chunk, 96 query heads over 8
+    KV heads of 128, 4,624-key tables, a 1,601-page pool) needs a fraction
+    of the gather oracle's temporaries, whose float32 scores span the whole
+    table: that cell's step leaves about 1 GB of the chip free."""
+    from repro.kernels import ops, ref
+
+    B, Sq, Hq, Hkv, hd, n, mp = 8, 256, 96, 8, 128, 1601, 289
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in [
+        ((B, Sq, Hq, hd), _BF16), ((n, PS, Hkv * hd), _BF16),
+        ((n, PS, Hkv * hd), _BF16), ((B, mp), _I32), ((B,), _I32),
+        ((B,), _I32)]]
+
+    def served(*a):
+        return ops.paged_prefill_chunk_attention(*a, use_pallas=False)
+
+    def oracle(q, k, v, *rest):
+        return ref.paged_prefill_attention_ref(
+            q, k.reshape(n, PS, Hkv, hd), v.reshape(n, PS, Hkv, hd), *rest)
+
+    def temp(fn):
+        return jax.jit(fn).lower(*args).compile().memory_analysis().temp_size_in_bytes
+
+    served_b, oracle_b = temp(served), temp(oracle)
+    assert served_b < 1e9 and served_b < oracle_b / 4, (served_b, oracle_b)
